@@ -53,6 +53,8 @@ def main() -> None:
                     help="also write collected rows to OUT as JSON "
                          "(e.g. BENCH_serve.json for the CI artifact)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import bench_gsc, bench_kwta, bench_resources, \
         bench_serve, bench_sparse_matmul
     mods = {"gsc": bench_gsc, "sparse_matmul": bench_sparse_matmul,

@@ -27,9 +27,13 @@ safe over-approximation for while/cond.
 
 from __future__ import annotations
 
-from typing import Callable, Iterator, List, NamedTuple, Sequence, Tuple
+from typing import (Callable, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
+import numpy as np
 from jax._src import core as jax_core
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Jaxpr = jax_core.Jaxpr
 ClosedJaxpr = jax_core.ClosedJaxpr
@@ -87,6 +91,39 @@ def iter_eqns(jaxpr, prefix: str = "", depth: int = 0,
             continue
         for sub in sub_jaxprs(eqn):
             yield from iter_eqns(sub, path, depth + 1, into_pallas)
+
+
+class BlockView(NamedTuple):
+    """What the static rules read off one ``pallas_call`` block mapping."""
+
+    block_shape: Tuple[Optional[int], ...]   # None = squeezed axis
+    array_shape: Tuple[int, ...]
+    dtype: np.dtype
+    in_smem: bool                            # scalar memory, not VMEM
+
+
+def _block_dim(b) -> Optional[int]:
+    return None if isinstance(b, pl.Squeezed) else int(b.block_size)
+
+
+def block_view(bm) -> BlockView:
+    """The one reader of Pallas' ``GridMapping.block_mappings`` entries.
+
+    Block dims arrive as ``pl.Blocked``/``pl.Squeezed`` objects and the
+    array as ``array_aval``; every rule reads them through here, so a
+    change of those private fields is repaired in one place."""
+    aval = bm.array_aval
+    space = getattr(bm.transformed_block_aval, "memory_space", None)
+    return BlockView(tuple(_block_dim(b) for b in bm.block_shape),
+                     tuple(int(d) for d in aval.shape), np.dtype(aval.dtype),
+                     space == pltpu.SMEM)
+
+
+def kernel_name(eqn) -> str:
+    """Name of the kernel body function a ``pallas_call`` eqn stages."""
+    info = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+    src = getattr(info, "func_src_info", None)
+    return src.split(" ")[0] if src else "pallas_call"
 
 
 class TaintHit(NamedTuple):

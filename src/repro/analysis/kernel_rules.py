@@ -60,7 +60,7 @@ from repro.kernels.block_validation import (block_bytes, estimate_vmem_bytes,
 
 from .findings import Finding
 from .intervals import TOP, AbsVal, Interval, Sym
-from .jaxpr_walk import iter_eqns, sub_jaxprs
+from .jaxpr_walk import block_view, iter_eqns, kernel_name, sub_jaxprs
 
 # ---------------------------------------------------------------------------
 # Ref bookkeeping
@@ -115,9 +115,10 @@ def register_value_ranges(kernel_name: str,
                                        Dict[int, Interval]]) -> None:
     """Declare the element ranges of a kernel's index-carrying operands.
 
-    ``kernel_name`` is the staged kernel body function name (the first
-    token of the ``pallas_call`` eqn's ``name_and_src_info``).  ``fn``
-    receives the operand :class:`RefInfo` list and returns a mapping
+    ``kernel_name`` is the staged kernel body's name, as
+    :func:`~repro.analysis.jaxpr_walk.kernel_name` reads it off the
+    ``pallas_call`` eqn.  ``fn`` receives the operand :class:`RefInfo`
+    list and returns a mapping
     from operand position to the :class:`Interval` its *values* are
     guaranteed to lie in.  The declaration is the verifier's trust root:
     register it next to the wrapper that constructs those operands, with
@@ -702,13 +703,12 @@ def _build_refs(body, gm) -> List[RefInfo]:
             kind, arr_shape, padded = "index", shape, ()
         elif i < n_idx + nin + nout:
             kind = "in" if i < n_idx + nin else "out"
-            bm = bms[i - n_idx] if i - n_idx < len(bms) else None
-            arr_shape = tuple(bm.array_shape_dtype.shape) if bm is not None \
-                else shape
+            bv = block_view(bms[i - n_idx]) if i - n_idx < len(bms) \
+                else None
+            arr_shape = bv.array_shape if bv is not None else shape
             padded = tuple(
-                ax for ax, (b, d) in enumerate(zip(bm.block_shape, arr_shape))
-                if isinstance(b, (int, np.integer)) and int(b) > 0
-                and d % int(b)) if bm is not None else ()
+                ax for ax, (b, d) in enumerate(zip(bv.block_shape, arr_shape))
+                if b and d % b) if bv is not None else ()
         else:
             kind, arr_shape, padded = "scratch", shape, ()
         refs.append(RefInfo(idx=i, kind=kind, block_shape=shape,
@@ -772,8 +772,8 @@ def _scratch_findings(ctx: _Ctx, refs: List[RefInfo], gm,
     if not scratch:
         return
     scratch_bytes = sum(block_bytes(r.block_shape, r.dtype) for r in scratch)
-    blocks = [(bm.block_shape, bm.array_shape_dtype.dtype)
-              for bm in gm.block_mappings]
+    blocks = [(bv.block_shape, bv.dtype)
+              for bv in map(block_view, gm.block_mappings) if not bv.in_smem]
     total = estimate_vmem_bytes(blocks) + scratch_bytes
     budget = vmem_budget(backend)
     if total > budget:
@@ -789,8 +789,7 @@ def verify_pallas_eqn(eqn, scope: str = "", entry: str = "",
     """Run the kernel-body rule families over one staged ``pallas_call``."""
     gm = eqn.params.get("grid_mapping")
     body = eqn.params.get("jaxpr")
-    kernel = str(eqn.params.get("name_and_src_info", "pallas_call"))
-    kernel = kernel.split(" ")[0]
+    kernel = kernel_name(eqn)
     ctx = _Ctx(kernel, entry, scope)
     if gm is None or body is None:    # pragma: no cover - jax API drift
         ctx.findings.append(Finding(

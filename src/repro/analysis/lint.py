@@ -398,11 +398,9 @@ def _regression_double_topk() -> Report:
 def _regression_f64_kernel() -> Report:
     """An f64 constant leaking into the sparse contraction: every value
     it touches promotes to float64 (only stageable under x64)."""
-    from jax.experimental import enable_x64
-
     from repro.core.functional import cs_topk_from_support, topk_support_flat
 
-    with enable_x64():
+    with jax.enable_x64(True):
         packed = _sds((16, 8, 4), jnp.float32)
         route = _sds((16, 8, 4), jnp.int32)
         x = _sds((2, 32), jnp.float32)

@@ -29,7 +29,8 @@ from repro.kernels.block_validation import (check_block_shape,
                                             estimate_vmem_bytes, vmem_budget)
 
 from .findings import Finding
-from .jaxpr_walk import iter_eqns, propagate_taint, sub_jaxprs
+from .jaxpr_walk import (block_view, iter_eqns, kernel_name,
+                         propagate_taint, sub_jaxprs)
 
 #: Primitives that implement a Select (top-k winner choice).  ``sort`` is
 #: counted too: a sort-based k-WTA is a Select with a worse lowering.
@@ -244,19 +245,18 @@ def rule_pallas_resource(closed_jaxpr, entry: str = "",
                 message="pallas_call without grid_mapping param; cannot "
                         "check BlockSpecs (jax API drift?)"))
             continue
-        name = str(eqn.params.get("name_and_src_info", "pallas_call"))
-        name = name.split(" ")[0]
+        name = kernel_name(eqn)
         blocks = []
-        for bm in gm.block_mappings:
-            arr = bm.array_shape_dtype
-            for problem in check_block_shape(bm.block_shape, arr.shape):
+        for bv in map(block_view, gm.block_mappings):
+            for problem in check_block_shape(bv.block_shape, bv.array_shape):
                 out.append(Finding(
                     rule="pallas-resource", entry=entry, scope=path,
                     primitive=name,
                     message=f"kernel {name}: BlockSpec "
-                            f"{_block_shape_ints(bm.block_shape)} vs array "
-                            f"{tuple(arr.shape)}: {problem}"))
-            blocks.append((bm.block_shape, arr.dtype))
+                            f"{_block_shape_ints(bv.block_shape)} vs array "
+                            f"{bv.array_shape}: {problem}"))
+            if not bv.in_smem:
+                blocks.append((bv.block_shape, bv.dtype))
         vmem = estimate_vmem_bytes(blocks)
         if vmem > budget:
             out.append(Finding(

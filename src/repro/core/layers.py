@@ -21,8 +21,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.obs import sparsity as obs_sparsity
+from repro.sharding.context import get_rules
 
 from . import functional as F
 from .api import (SparsityConfig, choose_executor, choose_path,
@@ -123,8 +125,17 @@ def _topk_execute(vals, idx, packed, route, cfg: SparsityConfig):
     if ex.use_pallas:
         # deferred import: kernels.ops imports repro.core at module scope
         from repro.kernels.ops import topk_gather_support_op
-        return topk_gather_support_op(vals, p_idx, s_off, packed, route,
-                                      ex.interpret)
+
+        def call(*args):
+            return topk_gather_support_op(*args, ex.interpret)
+
+        rules = get_rules()
+        if rules is not None and rules.mesh.size > 1:
+            # The compiler cannot partition a Mosaic kernel: every device
+            # runs it on replicated (all-gathered) operands.
+            call = jax.shard_map(call, mesh=rules.mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False)
+        return call(vals, p_idx, s_off, packed, route)
     return F.cs_topk_from_support(vals, p_idx, s_off, packed, route)
 
 

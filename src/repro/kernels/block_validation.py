@@ -26,7 +26,12 @@ import numpy as np
 #: budget anyway — that is the point of linting on CPU in CI.
 VMEM_BYTES = {"tpu": 16 * 2 ** 20}
 VMEM_BUDGET_BYTES = {k: v // 2 for k, v in VMEM_BYTES.items()}
-DEFAULT_VMEM_BUDGET = VMEM_BUDGET_BYTES["tpu"]
+
+#: TPU vreg tiling of a VMEM buffer: its last axis pads to a multiple of
+#: LANES; its second-to-last to SUBLANES rows of 32-bit words, i.e. 8 rows
+#: for 32-bit types, 16 for 16-bit and 32 for 8-bit types.
+LANES = 128
+SUBLANES = 8
 
 
 def validate_block(name: str, block: int, dim: int, dim_name: str,
@@ -86,13 +91,25 @@ def check_block_shape(block_shape: Sequence, array_shape: Sequence[int],
     return problems
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 def block_bytes(block_shape: Sequence, dtype) -> int:
-    """Bytes of one block (non-integer/mapped entries count as 1)."""
-    n = 1
-    for b in block_shape:
-        if isinstance(b, (int, np.integer)):
-            n *= int(b)
-    return n * np.dtype(dtype).itemsize
+    """VMEM bytes of one block, tile padding included.
+
+    Non-integer (squeezed/mapped) entries count as 1.  The last two axes
+    pad to the vreg tile (see :data:`LANES`, :data:`SUBLANES`): a
+    (640, 240, 4) f32 block occupies (640, 240 -> 248, 4 -> 128) words.
+    """
+    dims = [int(b) if isinstance(b, (int, np.integer)) else 1
+            for b in block_shape]
+    itemsize = np.dtype(dtype).itemsize
+    if dims:
+        dims[-1] = _round_up(dims[-1], LANES)
+    if len(dims) > 1:
+        dims[-2] = _round_up(dims[-2], SUBLANES * max(4 // itemsize, 1))
+    return int(np.prod(dims, dtype=np.int64)) * itemsize
 
 
 def estimate_vmem_bytes(blocks: Sequence[Tuple[Sequence, object]]) -> int:
@@ -105,5 +122,10 @@ def estimate_vmem_bytes(blocks: Sequence[Tuple[Sequence, object]]) -> int:
 
 
 def vmem_budget(backend: Optional[str] = None) -> int:
-    """VMEM lint budget for ``backend`` (default: the TPU budget)."""
-    return VMEM_BUDGET_BYTES.get(backend or "tpu", DEFAULT_VMEM_BUDGET)
+    """VMEM lint budget for ``backend`` (default: the TPU budget).
+
+    A backend without a known VMEM size is an error, not a default."""
+    backend = backend or "tpu"
+    if backend not in VMEM_BUDGET_BYTES:
+        raise ValueError(f"no VMEM size known for backend {backend!r}")
+    return VMEM_BUDGET_BYTES[backend]

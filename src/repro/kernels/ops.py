@@ -1,10 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels, with custom VJPs so the
 kernels are usable inside training graphs.
 
-Forward = Pallas kernel (or the jnp fallback when ``use_pallas=False`` /
-running on a non-TPU backend); backward = the sparse-cost jnp formulas from
+Forward = Pallas kernel; backward = the sparse-cost jnp formulas from
 repro.core.functional (static gathers/scatters — same N-fold savings as the
-forward, see DESIGN.md §3).
+forward, see DESIGN.md §3).  ``interpret`` is passed through unchanged:
+whether a kernel runs compiled or in interpret mode is decided once, by
+:func:`repro.core.api.choose_executor`.
 """
 
 from __future__ import annotations
@@ -24,10 +25,6 @@ from .ref import ref_kwta_hist
 from .topk_gather import topk_gather_matmul, topk_support
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # packed matmul op (decompress-in-VMEM MXU path)
 # ---------------------------------------------------------------------------
@@ -36,7 +33,7 @@ def _on_tpu() -> bool:
 def packed_matmul_op(x, packed, route, interpret: bool = False):
     """y = x @ decompress(packed, route); forward via the Pallas kernel."""
     pr, rr = to_partition_major(packed, route)
-    y = packed_matmul(x, pr, rr, interpret=interpret or not _on_tpu())
+    y = packed_matmul(x, pr, rr, interpret=interpret)
     return y.astype(x.dtype)
 
 
@@ -71,7 +68,7 @@ packed_matmul_op.defvjp(_pm_fwd, _pm_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def grouped_cs_matmul_op(xg, packed_s, interpret: bool = False):
     """out[s] = xg[s] @ packed_s[s]; (N, B, P) x (N, P, G) -> (N, B, G)."""
-    y = grouped_cs_matmul(xg, packed_s, interpret=interpret or not _on_tpu())
+    y = grouped_cs_matmul(xg, packed_s, interpret=interpret)
     return y.astype(xg.dtype)
 
 
@@ -118,7 +115,7 @@ def topk_gather_support_op(vals, p_idx, s_off, packed, route,
     pr, rr = to_partition_major(packed, route)
     y = topk_gather_matmul(vals.astype(jnp.float32).reshape(-1, k),
                            p_idx.reshape(-1, k), s_off.reshape(-1, k),
-                           pr, rr, interpret=interpret or not _on_tpu())
+                           pr, rr, interpret=interpret)
     return y.reshape(*lead, g * n).astype(vals.dtype)
 
 
@@ -174,7 +171,7 @@ def topk_gather_op(x, packed, route, k: int, interpret: bool = False):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def kwta_hist_op(x, k: int, interpret: bool = False):
-    return kwta_hist_pallas(x, k, interpret=interpret or not _on_tpu())
+    return kwta_hist_pallas(x, k, interpret=interpret)
 
 
 def _kh_fwd(x, k, interpret):

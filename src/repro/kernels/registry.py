@@ -53,11 +53,14 @@ def _trace(fn, *args, **static):
 # (single-tile grids, multi-k accumulation grids, batched decode grids).
 # ---------------------------------------------------------------------------
 
-#: topk_gather_matmul: (b, k_nnz, p, g, n, block_g)
+#: topk_gather_matmul: (b, k_nnz, p, g, n, block_g); a group tile spans
+#: block_g*n lanes, a multiple of 128 unless it covers all g groups.
 TOPK_GATHER_SWEEP = (
     (4, 16, 32, 8, 4, 8),       # decode batch, single group tile
-    (8, 32, 64, 16, 4, 8),      # grid (2, 8): group-tiled, batch innermost
-    (2, 8, 16, 4, 4, 2),        # tiny shapes, block_g < g
+    (8, 32, 64, 64, 4, 32),     # grid (2, 8): group-tiled, batch innermost
+    (2, 8, 16, 4, 4, 4),        # tiny shapes
+    (4, 320, 640, 240, 4, 240),  # smollm-360m FFN down-projection, decode
+    (7, 320, 640, 240, 4, 240),  # ... at the largest topk-path batch
 )
 
 #: grouped_cs_matmul: (n, b, p, g, block_b, block_p, block_g)
@@ -97,7 +100,7 @@ def kernel_cases() -> List[KernelCase]:
             lambda b=b, k=k, p=p, g=g, n=n, bg=bg: _trace(
                 topk_gather_matmul,
                 _sds((b, k), jnp.float32), _sds((b, k), jnp.int32),
-                _sds((b, k), jnp.int32), _sds((p, g, n), jnp.float32),
+                _sds((b, k), jnp.int32), _sds((p, g, n), jnp.bfloat16),
                 _sds((p, g, n), jnp.int8), block_g=bg)))
 
     for n, b, p, g, bb, bp, bg in GROUPED_CS_SWEEP:
@@ -152,15 +155,12 @@ def ensure_provenance() -> None:
     from repro.analysis.kernel_rules import register_value_ranges
 
     def topk_gather_ranges(refs):
-        # topk_support computes p_idx = sel // n and s_off = sel % n from
-        # counted_top_k over the flat [0, P*N) activation index space, so
-        # p_idx ∈ [0, P) and s_off ∈ [0, N) by construction.  The packed
-        # operand (position 3) is block-resident along its full partition
-        # dim, so P/N are read off its block shape.
-        packed = refs[3]
-        p, n = packed.block_shape[0], packed.block_shape[2]
-        return {1: Interval(0, p - 1),     # pidx_ref values
-                2: Interval(0, n - 1)}     # soff_ref values
+        # topk_support computes p_idx = sel // n from counted_top_k over
+        # the flat [0, P*N) activation index space, so p_idx ∈ [0, P) by
+        # construction.  The packed operand (position 3) is block-resident
+        # along its full partition dim, so P is read off its block shape.
+        # s_off is only compared against routes, never used as an index.
+        return {1: Interval(0, refs[3].block_shape[0] - 1)}   # pidx_ref
 
     register_value_ranges("_topk_gather_kernel", topk_gather_ranges)
     # grouped_cs / packed_matmul / kwta_hist index only with program_id

@@ -2,29 +2,38 @@
 
 Implements the five-step sparse-sparse pipeline's hot loop: for each of the
 K non-zero activations, fetch the corresponding packed weight row (the
-paper's 'K-ported weight memory' becomes K sequential VMEM dynamic slices —
-on TPU, parallelism comes from the (G, N) lane dimensions of each fetched
-row instead of from memory ports), mask by Kernel-ID match (route ==
-offset), scale by the activation value, and accumulate.
+paper's 'K-ported weight memory' becomes K sequential VMEM row loads — on
+TPU, parallelism comes from the G·N lanes of each fetched row instead of
+from memory ports), mask by Kernel-ID match (route == offset), scale by the
+activation value, and accumulate.
 
 FLOPs: 2·B·K·D_out — the multiplicative sparse-sparse saving
 (D_in/K from activations × N from weights on the memory side).
 
 Layouts:
-  vals   (B, K)       activation values (f32)
-  p_idx  (B, K) int32 partition index of each non-zero
-  s_off  (B, K) int32 offset-within-partition of each non-zero
-  packed (P, G, N)    partition-major packed weights
-  route  (P, G, N)    int8
+  vals   (B, K)       activation values (f32)          SMEM, whole array
+  p_idx  (B, K) int32 partition index of each non-zero  SMEM, whole array
+  s_off  (B, K) int32 offset-within-partition           SMEM, whole array
+  packed (P, G, N)    partition-major packed weights    VMEM as (P, G·N) f32
+  route  (P, G, N)    routes                            VMEM as (P, G·N) i32
   out    (B, G*N)     f32
 
-Grid: (nG, B) — batch innermost.  Each step loops over K with a fori_loop
-of dynamic row loads; the weight tile (P, block_g, N) stays VMEM-resident
-across the K loop AND across the whole decode batch: with B as the fastest
-grid dimension the packed/route index maps are constant while b sweeps, so
-Pallas' revisit caching skips the re-fetch and one launch serves every
-decode slot (the batched-decode regime of arXiv 2311.07625 — weight reads
-amortize over B, which is where weight × activation sparsity multiply).
+The support is scalar data (one row index, one offset and one scale per
+non-zero), so it lives in SMEM and the K loop reads it with scalar loads.
+The weights are lane-dense: (P, G·N) puts the G·N output columns on the
+128-wide lane axis, so one dynamic row load fetches a partition's whole
+output row with no in-kernel reshape.  Mosaic loads a single row at a
+dynamic sublane offset only from 32-bit refs, so the wrapper widens the
+weights to f32 and the routes to int32 before the call.
+
+Grid: (nG, B) — batch innermost.  The weight tile (P, block_g·N) stays
+VMEM-resident across the K loop AND across the whole decode batch: with B
+as the fastest grid dimension the packed/route index maps are constant
+while b sweeps, so Pallas' revisit caching skips the re-fetch and one
+launch serves every decode slot (the batched-decode regime of arXiv
+2311.07625 — weight reads amortize over B, which is where weight ×
+activation sparsity multiply).  A group tile must span a multiple of 128
+lanes (block_g·N % 128 == 0) unless it covers all G groups.
 """
 
 from __future__ import annotations
@@ -35,26 +44,24 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .block_validation import validate_block
+from .block_validation import LANES, validate_block
 
 
-def _topk_gather_kernel(vals_ref, pidx_ref, soff_ref, packed_ref, route_ref, o_ref,
-            *, k_nnz: int):
-    vals = vals_ref[0]            # (K,)
-    pidx = pidx_ref[0]            # (K,)
-    soff = soff_ref[0]            # (K,)
-    bg, n = packed_ref.shape[1], packed_ref.shape[2]
+def _topk_gather_kernel(vals_ref, pidx_ref, soff_ref, packed_ref, route_ref,
+                        o_ref, *, k_nnz: int):
+    b = pl.program_id(1)
 
     def body(j, acc):
-        p = pidx[j]
-        w = packed_ref[pl.ds(p, 1), :, :][0]
-        r = route_ref[pl.ds(p, 1), :, :][0]
-        hit = r == soff[j].astype(r.dtype)
-        return acc + jnp.where(hit, w.astype(jnp.float32), 0.0) * vals[j]
+        p = pidx_ref[b, j]
+        w = packed_ref[pl.ds(p, 1), :]            # (1, block_g*N) f32
+        r = route_ref[pl.ds(p, 1), :]             # (1, block_g*N) int32
+        hit = r == soff_ref[b, j]
+        return acc + jnp.where(hit, w, 0.0) * vals_ref[b, j]
 
-    acc = lax.fori_loop(0, k_nnz, body, jnp.zeros((bg, n), jnp.float32))
-    o_ref[0] = acc.reshape(bg * n)
+    o_ref[...] = lax.fori_loop(0, k_nnz, body,
+                               jnp.zeros(o_ref.shape, jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_g", "interpret"))
@@ -74,24 +81,27 @@ def topk_gather_matmul(vals: jax.Array, p_idx: jax.Array, s_off: jax.Array,
     # Explicit-block convention: an oversized block_g is the caller's error,
     # not something to clamp away (shared validator, clamp=False).
     block_g = validate_block("block_g", block_g, g, "G", clamp=False)
-    # Grid order (nG, B): batch innermost so the packed/route tiles (index
-    # maps ignore ib) are revisited — fetched once per group tile, resident
-    # in VMEM for the whole decode batch.
-    return pl.pallas_call(
+    if block_g < g and (block_g * n) % LANES:
+        raise ValueError(f"block_g={block_g}: block_g*N={block_g * n} must "
+                         f"be a multiple of {LANES} lanes (or block_g=G)")
+    width = block_g * n
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
         functools.partial(_topk_gather_kernel, k_nnz=k_nnz),
         grid=(g // block_g, b),
         in_specs=[
-            pl.BlockSpec((1, k_nnz), lambda ig, ib: (ib, 0)),
-            pl.BlockSpec((1, k_nnz), lambda ig, ib: (ib, 0)),
-            pl.BlockSpec((1, k_nnz), lambda ig, ib: (ib, 0)),
-            pl.BlockSpec((p, block_g, n), lambda ig, ib: (0, ig, 0)),
-            pl.BlockSpec((p, block_g, n), lambda ig, ib: (0, ig, 0)),
+            smem, smem, smem,
+            pl.BlockSpec((p, width), lambda ig, ib: (0, ig)),
+            pl.BlockSpec((p, width), lambda ig, ib: (0, ig)),
         ],
-        out_specs=pl.BlockSpec((1, block_g * n), lambda ig, ib: (ib, ig)),
-        out_shape=jax.ShapeDtypeStruct((b, g * n), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, width), lambda ig, ib: (ib, 0, ig)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, g * n), jnp.float32),
         interpret=interpret,
-    )(vals, p_idx.astype(jnp.int32), s_off.astype(jnp.int32),
-      packed_p, route_p)
+    )(vals.astype(jnp.float32), p_idx.astype(jnp.int32),
+      s_off.astype(jnp.int32),
+      packed_p.reshape(p, g * n).astype(jnp.float32),
+      route_p.reshape(p, g * n).astype(jnp.int32))
+    return out.reshape(b, g * n)
 
 
 def topk_support(x: jax.Array, k: int, n: int):

@@ -27,15 +27,9 @@ from typing import Dict, List, Set, Tuple
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to a flat dict.
-
-    JAX has flip-flopped on the return shape (a dict on new versions, a
-    one-element list of dicts on 0.4.x); every caller in this repo goes
-    through here so benchmarks and tests are version-tolerant."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def compiled_flops(compiled) -> float:

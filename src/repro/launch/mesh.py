@@ -10,33 +10,21 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh``: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer JAX; older versions
-    (<= 0.4.37) treat every axis as Auto already, so dropping the kwarg is
-    semantics-preserving."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """The assigned production meshes: 16x16 per pod (256 chips), and the
     2-pod 512-chip mesh with a leading 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
     """Arbitrary mesh for tests / small runs (e.g. (2, 2) on 4 CPU
-    devices)."""
-    return _make_mesh(tuple(shape), tuple(axes))
+    devices); ``devices`` picks which (default: all of them).  Every axis
+    is Auto: the sharding rules annotate logical axes and leave the
+    partitioning to the compiler."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh():

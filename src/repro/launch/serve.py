@@ -66,7 +66,7 @@ picture as a JSON-ready dict, live or at end of run.  With the default
 ``telemetry=None`` everything degrades to null objects and the staged
 step program is bit-identical to the un-instrumented one.
 
-Usage:
+Usage (published widths; add ``--reduced`` for the small CPU variant):
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
       --slots 4 --requests 8 --prompt-len 16 --gen 32
 """
@@ -88,6 +88,7 @@ from jax import lax
 
 from repro.configs import get_config
 from repro.core.api import observe_dispatch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.obs import DispatchStats, SparsityStats, Telemetry
@@ -245,6 +246,23 @@ class Engine:
                                               geo.page_size)
             shard = param_sharding(specs, cache, self.rules)
             return jax.device_put(cache, shard)
+
+    def lower_decode_step(self):
+        """Lower the decode step the serve loop runs (the paged one when
+        the engine is paged) at this engine's batch.  Its
+        ``.compile().as_text()`` shows which kernels (``tpu_custom_call``)
+        and collectives the served program holds."""
+        with use_rules(self.rules):
+            tokens = {"tokens": jnp.zeros((self.n_slots, 1), jnp.int32)}
+            pos = jnp.zeros((self.n_slots,), jnp.int32)
+            if self.kv_geo is None:
+                return self._step.lower(self.params,
+                                        self.new_cache(self.n_slots),
+                                        tokens, pos)
+            tables = jnp.asarray(self.kv_geo.empty_tables(self.n_slots))
+            return self._step_paged.lower(self.params,
+                                          self.new_paged_cache(), tokens,
+                                          pos, tables)
 
     def _prefill(self, prompt: Sequence[int]):
         """One fused-prefill call. Returns (last-position logits (vocab,),
@@ -792,7 +810,9 @@ def main():
     ap.add_argument("--mesh", default="1x1")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the config's small same-family variant "
+                    "(CPU smoke runs) instead of its published widths")
     ap.add_argument("--use-pallas", choices=("auto", "force", "off"),
                     default=None,
                     help="kernel executor override for the sparse paths "
@@ -825,6 +845,7 @@ def main():
                     "(implies --telemetry)")
     args = ap.parse_args()
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

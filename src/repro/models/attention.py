@@ -345,8 +345,7 @@ def _owner_write(cache, new, pos):
 
         return lax.cond(in_range, write, lambda c: c, c)
 
-    from repro.sharding.context import shard_map
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(cache_spec, new_spec, P()),
         out_specs=cache_spec, check_vma=False,
     )(cache, new, pos if hasattr(pos, "dtype") else jnp.int32(pos))

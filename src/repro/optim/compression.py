@@ -82,8 +82,7 @@ def make_compressed_grad_sync(mesh: Mesh, axis: str = "pod"):
     g_spec, r_spec = P(), P(axis)
 
     def sync(grads, resids):
-        from repro.sharding.context import shard_map
-        return shard_map(
+        return jax.shard_map(
             sync_local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: g_spec, grads),
                       jax.tree.map(lambda _: r_spec, resids)),

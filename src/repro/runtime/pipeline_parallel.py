@@ -84,8 +84,7 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, axis: str,
         return outs.reshape(b, *x_local.shape[1:])
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    from repro.sharding.context import shard_map
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),

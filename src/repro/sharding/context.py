@@ -25,17 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-tolerant ``shard_map``: top-level ``jax.shard_map`` on new
-    JAX, ``jax.experimental.shard_map.shard_map`` (with its ``check_rep``
-    spelling of the kwarg) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 _STATE = threading.local()
 
 

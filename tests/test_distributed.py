@@ -124,8 +124,7 @@ def test_compressed_grad_sync_cross_pod():
         out, r_new = _ef_psum_leaf(g[0], r[0], "pod", 2)
         return out[None], r_new[None]
 
-    from repro.sharding.context import shard_map as _shard_map
-    out, resid_new = _shard_map(
+    out, resid_new = jax.shard_map(
         local, mesh=mesh, in_specs=(P("pod"), P("pod")),
         out_specs=(P("pod"), P("pod")), check_vma=False,
     )(grads["w"][:, :], resid["w"])

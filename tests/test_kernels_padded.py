@@ -86,11 +86,13 @@ def test_grouped_padded_batch(b, block_b):
 # topk_gather_matmul: group axis — pad packed/route G with zero groups
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("d_out,g_extra,block_g", [(24, 2, 4), (20, 1, 2)])
+# (a group tile spans block_g*N lanes: a multiple of 128)
+@pytest.mark.parametrize("d_out,g_extra,block_g",
+                         [(160, 24, 32), (200, 14, 32)])
 def test_topk_gather_padded_groups(d_out, g_extra, block_g):
     d_in, n, b, k = 64, 4, 4, 8
     w, packed, route = make_case(d_in, d_out, n, seed=7)
-    pr, rr = to_partition_major(packed, route)      # (P, G, N), G = 6
+    pr, rr = to_partition_major(packed, route)      # (P, G, N), G = 40, 50
     g = pr.shape[1]
     assert g % block_g, "case must exercise a non-divisible G"
     xs = kwta(jax.random.normal(jax.random.PRNGKey(3), (b, d_in)), k)

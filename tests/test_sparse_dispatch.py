@@ -65,14 +65,15 @@ def test_batched_kernel_matches_jnp_paths(route_share, b):
 
 
 def test_batched_kernel_block_g_tiling():
-    """block_g < G sweeps the group grid dimension; results must not move."""
-    d_in, d_out, n, k = 64, 64, 4, 8
+    """block_g < G sweeps the group grid dimension; results must not move.
+    A group tile spans block_g*N lanes, so block_g*N is a multiple of 128."""
+    d_in, d_out, n, k = 64, 512, 4, 8
     w, packed, route = make_case(d_in, d_out, n, seed=3)
     x = kwta(jax.random.normal(jax.random.PRNGKey(0), (4, d_in)), k)
     vals, p_idx, s_off = topk_support(x, k, n)
     pr, rr = to_partition_major(packed, route)
     full = topk_gather_matmul(vals, p_idx, s_off, pr, rr, interpret=True)
-    for block_g in (1, 2, 4, 8):
+    for block_g in (32, 64, 128):
         tiled = topk_gather_matmul(vals, p_idx, s_off, pr, rr,
                                    block_g=block_g, interpret=True)
         np.testing.assert_allclose(np.asarray(tiled), np.asarray(full),
@@ -102,6 +103,29 @@ def test_packed_linear_padded_bias_sliced_layout():
                                    atol=1e-4)
         np.testing.assert_allclose(np.asarray(y_self), np.asarray(y_ref),
                                    atol=1e-4)
+
+
+def test_kernel_on_multi_device_mesh_matches_jnp():
+    """On a mesh of more than one device the kernel runs inside a
+    replicated shard_map (no compiler partitions a Mosaic kernel); the
+    result must still equal the masked dense matmul."""
+    from repro.launch.mesh import make_mesh
+    from repro.sharding import make_rules, use_rules
+    d_in, d_out, n, k = 64, 32, 4, 8
+    cfg = SparsityConfig(n=n, k_frac=k / d_in, path="topk",
+                         use_pallas="force")
+    w, packed, route = make_case(d_in, d_out, n, seed=21)
+    params = {"packed": packed, "route": route}
+    x = kwta(jax.random.normal(jax.random.PRNGKey(3), (4, d_in)), k)
+    mesh = make_mesh((1, 4), ("data", "model"), devices=jax.devices()[:4])
+
+    def f(p, x):
+        return packed_linear_apply(p, x, cfg, x_is_sparse=True)
+
+    with use_rules(make_rules(mesh, "decode")):
+        assert "shard_map" in str(jax.make_jaxpr(f)(params, x))
+        y = jax.jit(f)(params, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w), atol=1e-4)
 
 
 def test_auto_path_crossover_consistency():
@@ -226,6 +250,13 @@ def test_topk_gather_rejects_oversized_block_g():
     v, pi, so, pr, rr = _kernel_args()
     with pytest.raises(ValueError, match=r"block_g=16 exceeds G=8"):
         topk_gather_matmul(v, pi, so, pr, rr, block_g=16)
+
+
+def test_topk_gather_rejects_lane_misaligned_block_g():
+    v, pi, so, pr, rr = _kernel_args()
+    with pytest.raises(ValueError, match=r"block_g\*N=16 must be a multiple "
+                       r"of 128 lanes"):
+        topk_gather_matmul(v, pi, so, pr, rr, block_g=4)
 
 
 def test_topk_gather_rejects_empty_support():
