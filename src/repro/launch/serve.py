@@ -54,9 +54,11 @@ the scheduling oracle.
 
 Telemetry (ISSUE 8): pass ``telemetry=repro.obs.Telemetry.on(...)`` and
 the engine traces spans around every stage (``schedule.admit`` /
-``prefill`` / ``insert`` / ``decode.step`` / ``sample``), samples
-queue-depth and slot-occupancy gauges each step, keeps per-request
-lifecycle records (scheduler-side), attributes the staged execution
+``prefill`` / ``insert`` / ``decode.step`` / ``sample``; the paged loop
+puts every host step in a named span, listed in
+``src/repro/obs/README.md``), samples queue-depth and slot-occupancy
+gauges each step, keeps per-request lifecycle records (scheduler-side),
+attributes the staged execution
 paths (``repro.core.api.observe_dispatch``), and — every
 ``telemetry.sparsity_every`` steps — decodes through a *probed* twin of
 the step jit whose extra outputs are the per-layer k-WTA winner sets, so
@@ -150,13 +152,18 @@ class Engine:
                 p_shard = param_sharding(specs, params, self.rules)
                 params = jax.device_put(params, p_shard)
             self.params = params
-        self._step = jax.jit(
-            lambda p, c, b, pos: T.serve_step(p, c, b, pos, cfg),
-            donate_argnums=(1,))
+        # Named functions, not lambdas: a device trace names each
+        # program after its function (``jit_decode_step_paged``).
+        def decode_step(p, c, b, pos):
+            return T.serve_step(p, c, b, pos, cfg)
+
+        def prefill(p, toks):
+            return T.prefill(p, {"tokens": toks}, cfg, max_seq)
+
+        self._step = jax.jit(decode_step, donate_argnums=(1,))
         # jit's shape-keyed cache compiles this once per prompt *bucket*
         # (prompts are padded to power-of-two lengths), not per prompt
-        self._prefill_jit = jax.jit(
-            lambda p, toks: T.prefill(p, {"tokens": toks}, cfg, max_seq))
+        self._prefill_jit = jax.jit(prefill)
         self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
         self.prefill_calls = 0  # one per admitted prompt (tests assert)
         # -- paged KV layout --------------------------------------------------
@@ -175,19 +182,22 @@ class Engine:
                                   else min(4 * self.kv_geo.page_size,
                                            self.kv_geo.view_len))
             self.kv_geo.chunk_spans(1, self.prefill_chunk)  # validates
-            self._step_paged = jax.jit(
-                lambda p, c, b, pos, pg: T.serve_step(p, c, b, pos, cfg,
-                                                      pages=pg),
-                donate_argnums=(1,))
-            self._chunk_jit = jax.jit(
-                lambda p, c, toks, pg, start, ln: T.prefill_chunk(
-                    p, c, {"tokens": toks}, start, ln, cfg, pg),
-                donate_argnums=(1,))
+            def decode_step_paged(p, c, b, pos, pg):
+                return T.serve_step(p, c, b, pos, cfg, pages=pg)
+
+            def prefill_chunk(p, c, toks, pg, start, ln):
+                return T.prefill_chunk(p, c, {"tokens": toks}, start, ln,
+                                       cfg, pg)
+
             # copy-on-write break: clone page src's rows onto dst in every
             # pool leaf (traced ids -> one compile, reused for every CoW)
-            self._copy_page_jit = jax.jit(
-                lambda c, src, dst: T.copy_cache_page(c, src, dst),
-                donate_argnums=(0,))
+            def copy_page(c, src, dst):
+                return T.copy_cache_page(c, src, dst)
+
+            self._step_paged = jax.jit(decode_step_paged,
+                                       donate_argnums=(1,))
+            self._chunk_jit = jax.jit(prefill_chunk, donate_argnums=(1,))
+            self._copy_page_jit = jax.jit(copy_page, donate_argnums=(0,))
 
             def _probed_step_paged(p, c, b, pos, pg):
                 with obs_sparsity.capture_supports() as cap:
@@ -375,15 +385,17 @@ class Engine:
                 probed = probe_every > 0 and n_steps % probe_every == 0
                 t_step = time.perf_counter()
                 with tracer.span("decode.step", probed=probed), obs_ctx:
-                    step_in = ({"tokens": jnp.asarray(tokens)},
-                               jnp.asarray(pos))
+                    with tracer.span("decode.inputs"):
+                        step_in = ({"tokens": jnp.asarray(tokens)},
+                                   jnp.asarray(pos))
                     if probed:
                         logits, cache, sp_aux = self._step_probed(
                             self.params, cache, *step_in)
                     else:
                         logits, cache = self._step(self.params, cache,
                                                    *step_in)
-                    logits = np.asarray(logits)
+                    with tracer.span("decode.fetch"):
+                        logits = np.asarray(logits)
                 self._dispatch.seal()
                 dt_step = time.perf_counter() - t_step
                 h_step.observe(dt_step)
@@ -458,43 +470,47 @@ class Engine:
         (instead of only on drain) — the paranoid mode the fuzz harness
         and the CI paged-smoke step serve under.
         """
-        geo = self.kv_geo
-        alloc = BlockAllocator(geo.n_pages, geo.page_size)
-        for r in requests:
-            need = alloc.pages_needed(len(r.prompt) + r.max_new_tokens)
-            if need > alloc.capacity:
-                raise ValueError(
-                    f"request {r.uid}: needs {need} KV pages, pool holds "
-                    f"{alloc.capacity} — raise n_pages")
-        grow = self.kv_policy == "grow"
-        paranoid = os.environ.get("REPRO_KV_CHECK") == "1"
         tel = self.telemetry
         tracer = tel.tracer
         reg = tel.registry
-        g_queue = reg.gauge("serve.queue_depth")
-        g_active = reg.gauge("serve.slots_active")
-        g_occ = reg.gauge("serve.slot_occupancy")
-        h_chunk = reg.histogram("serve.prefill_chunk_s")
-        h_step = reg.histogram("serve.decode_step_s")
-        h_step_recent = reg.rolling_histogram("serve.decode_step_recent_s")
-        c_steps = reg.counter("serve.decode_steps")
-        c_chunks = reg.counter("serve.prefill_chunks")
-        c_cow = reg.counter("serve.cow_copies")
-        c_grow = reg.counter("serve.kv_grow_pages")
-        probe_every = tel.sparsity_every if tel.enabled else 0
-        sched = Scheduler(self.n_slots, telemetry=tel, allocator=alloc,
-                          kv_policy=self.kv_policy)
-        self._last_sched = sched
-        sched.submit_many(requests, now=0.0)
-        tables = geo.empty_tables(self.n_slots)
-        chunk = self.prefill_chunk
-        ps = geo.page_size
-        n_chunks = 0
-        n_cow = 0
-        n_cow_inplace = 0
-        n_grown = 0
-        max_concurrent = 0
-        prefillq: "deque" = deque()  # slots mid-prompt, FIFO
+        with tracer.span("serve.setup"):
+            geo = self.kv_geo
+            alloc = BlockAllocator(geo.n_pages, geo.page_size)
+            for r in requests:
+                need = alloc.pages_needed(len(r.prompt) + r.max_new_tokens)
+                if need > alloc.capacity:
+                    raise ValueError(
+                        f"request {r.uid}: needs {need} KV pages, pool "
+                        f"holds {alloc.capacity} — raise n_pages")
+            grow = self.kv_policy == "grow"
+            paranoid = os.environ.get("REPRO_KV_CHECK") == "1"
+            g_queue = reg.gauge("serve.queue_depth")
+            g_active = reg.gauge("serve.slots_active")
+            g_occ = reg.gauge("serve.slot_occupancy")
+            h_step = reg.histogram("serve.decode_step_s")
+            h_step_recent = reg.rolling_histogram(
+                "serve.decode_step_recent_s")
+            c_steps = reg.counter("serve.decode_steps")
+            c_chunks = reg.counter("serve.prefill_chunks")
+            c_cow = reg.counter("serve.cow_copies")
+            c_grow = reg.counter("serve.kv_grow_pages")
+            probe_every = tel.sparsity_every if tel.enabled else 0
+            sched = Scheduler(self.n_slots, telemetry=tel, allocator=alloc,
+                              kv_policy=self.kv_policy)
+            self._last_sched = sched
+            sched.submit_many(requests, now=0.0)
+            tables = geo.empty_tables(self.n_slots)
+            chunk = self.prefill_chunk
+            ps = geo.page_size
+            n_chunks = 0
+            n_cow = 0
+            n_cow_inplace = 0
+            n_grown = 0
+            max_concurrent = 0
+            prefillq: "deque" = deque()  # slots mid-prompt, FIFO
+            cache = self.new_paged_cache()
+            tokens = np.zeros((self.n_slots, 1), np.int32)
+            pos = np.zeros((self.n_slots,), np.int32)
 
         def _evict(victim):
             """Preempt ``victim``: null its page table, drop it from the
@@ -547,185 +563,204 @@ class Engine:
             c_cow.inc()
             return True
 
-        with use_rules(self.rules):
-            cache = self.new_paged_cache()
-            tokens = np.zeros((self.n_slots, 1), np.int32)
-            pos = np.zeros((self.n_slots,), np.int32)
-            n_steps = 0
-            t0 = time.perf_counter()
-            while sched.has_work:
-                if paranoid:
-                    alloc.check()
-                with tracer.span("schedule.admit"):
-                    admitted = sched.admit(now=time.perf_counter() - t0,
-                                           chunked=True)
-                for slot in admitted:
-                    self._sparsity.reset_row(slot.index)
-                    geo.set_chain(tables, slot.index,
-                                  alloc.chain(slot.request.uid))
-                    prefillq.append(slot)
-                max_concurrent = max(max_concurrent,
-                                     len(sched.active_slots()))
-                # ONE chunk per iteration: prefill progress is interleaved
-                # with decode so in-flight slots keep emitting tokens.
-                if prefillq:
-                    slot = prefillq[0]
-                    req = slot.request
-                    start = slot.prefill_pos
-                    ln = min(chunk, len(req.prompt) - start)
+        def _grow():
+            """Grow every decoding slot's chain to cover its next write,
+            oldest-admitted first (the youngest is the preemption victim,
+            so growing oldest-first means a victim's freed pages go to
+            the slots that keep running).  A slot evicted by an earlier
+            _ensure_free in this very loop shows up as not busy — skip
+            it."""
+            nonlocal n_grown
+            for slot in sorted(sched.decoding_slots(),
+                               key=lambda s: s.admit_seq):
+                if not slot.busy:
+                    continue
+                uid = slot.request.uid
+                evicted = False
+                while alloc.chain_len(uid) <= slot.pos // ps:
+                    if alloc.free_pages < 1 and not _ensure_free(1, slot):
+                        evicted = True
+                        break
+                    alloc.extend(uid, 1)
+                    n_grown += 1
+                    c_grow.inc()
+                if evicted or not slot.busy:
+                    continue
+                # the write row may sit in a page adopted from a
+                # prompt-prefix match: break the sharing first
+                if not _cow(slot, slot.pos // ps):
+                    continue
+                geo.set_chain(tables, slot.index, alloc.chain(uid))
+
+        def _prefill_next_chunk():
+            """Forward one chunk of the oldest prefilling slot; after its
+            last chunk, sample the request's first token."""
+            nonlocal cache, n_chunks
+            slot = prefillq[0]
+            req = slot.request
+            start = slot.prefill_pos
+            ln = min(chunk, len(req.prompt) - start)
+            with tracer.span("prefill.chunk", uid=req.uid, start=start,
+                             chunk_len=ln):
+                with tracer.span("prefill.inputs"):
                     # chunk rows may land in adopted prefix pages (an
                     # exact-duplicate prompt re-prefills its final token
                     # into the sharer's last page): break the sharing
-                    # first.  _cow can preempt, including this very
-                    # slot — then skip the chunk, the request is back in
-                    # the queue.
-                    ok = True
-                    if grow:
-                        for blk in range(start // ps,
-                                         (start + ln - 1) // ps + 1):
-                            if not _cow(slot, blk):
-                                ok = False
-                                break
-                    if ok:
-                        buf = np.zeros((1, chunk), np.int32)
-                        buf[0, :ln] = np.asarray(
-                            req.prompt[start:start + ln], np.int32)
-                        t_pre = time.perf_counter()
-                        with tracer.span("prefill.chunk", uid=req.uid,
-                                         start=start, chunk_len=ln):
-                            logits, cache = self._chunk_jit(
-                                self.params, cache, jnp.asarray(buf),
-                                jnp.asarray(
-                                    tables[slot.index:slot.index + 1]),
+                    # first.  _cow can preempt, including this very slot —
+                    # then skip the chunk, the request is back in the
+                    # queue.
+                    if grow and not all(
+                            _cow(slot, blk) for blk in range(
+                                start // ps, (start + ln - 1) // ps + 1)):
+                        return
+                    buf = np.zeros((1, chunk), np.int32)
+                    buf[0, :ln] = np.asarray(req.prompt[start:start + ln],
+                                             np.int32)
+                    chunk_in = (jnp.asarray(buf),
+                                jnp.asarray(tables[slot.index:slot.index + 1]),
                                 jnp.int32(start), jnp.int32(ln))
-                        h_chunk.observe(time.perf_counter() - t_pre)
-                        c_chunks.inc()
-                        n_chunks += 1
-                        slot.prefill_pos += ln
-                    if ok and not slot.prefilling:  # last chunk
-                        prefillq.popleft()
-                        self.prefill_calls += 1
-                        reg.counter("serve.prefill_calls").inc()
-                        if grow:
-                            # rows are on device now — publish the
-                            # prompt's pages for later prefix matches
-                            alloc.register_chain_prefix(
-                                req.uid, prefix_keys(req.prompt, ps))
-                        row = np.asarray(logits[0, ln - 1])
-                        with tracer.span("sample"):
-                            first = sample_token(row, req.sampling,
-                                                 slot.rng)
-                        sched.record_token(slot, first,
-                                           now=time.perf_counter() - t0)
-                        tokens[slot.index, 0] = first
-                        pos[slot.index] = slot.pos  # == len(prompt)
-                # budget-1 requests finish at prefill
-                for slot in sched.retire_done(now=time.perf_counter() - t0):
-                    geo.clear_chain(tables, slot.index)
-                if grow:
-                    # grow every decoding slot's chain to cover its next
-                    # write, oldest-admitted first (the youngest is the
-                    # preemption victim, so growing oldest-first means a
-                    # victim's freed pages go to the slots that keep
-                    # running).  A slot evicted by an earlier _ensure_free
-                    # in this very loop shows up as not busy — skip it.
-                    for slot in sorted(sched.decoding_slots(),
-                                       key=lambda s: s.admit_seq):
-                        if not slot.busy:
-                            continue
-                        uid = slot.request.uid
-                        evicted = False
-                        while alloc.chain_len(uid) <= slot.pos // ps:
-                            if alloc.free_pages < 1 \
-                                    and not _ensure_free(1, slot):
-                                evicted = True
-                                break
-                            alloc.extend(uid, 1)
-                            n_grown += 1
-                            c_grow.inc()
-                        if evicted or not slot.busy:
-                            continue
-                        # the write row may sit in a page adopted from a
-                        # prompt-prefix match: break the sharing first
-                        if not _cow(slot, slot.pos // ps):
-                            continue
-                        geo.set_chain(tables, slot.index, alloc.chain(uid))
-                active = sched.decoding_slots()
-                g_queue.set(len(sched.queue))
-                g_active.set(len(active))
-                g_occ.set(len(active) / self.n_slots)
-                if not active:
-                    continue
-                # Null the page-table rows of slots sitting this step out
-                # (free, or mid-prefill): their stale token/pos rows still
-                # ride the batch, but their writes sink to the null page.
-                step_tables = tables.copy()
-                decoding = {s.index for s in active}
-                for i in range(self.n_slots):
-                    if i not in decoding:
-                        step_tables[i, :] = NULL_PAGE
-                obs_ctx = (observe_dispatch(self._dispatch.on_event)
-                           if tel.enabled and not self._dispatch.sealed
-                           else contextlib.nullcontext())
-                probed = probe_every > 0 and n_steps % probe_every == 0
-                t_step = time.perf_counter()
-                with tracer.span("decode.step", probed=probed), obs_ctx:
-                    step_in = ({"tokens": jnp.asarray(tokens)},
-                               jnp.asarray(pos), jnp.asarray(step_tables))
+                # the jit call sits directly in prefill.chunk, with no
+                # child span open (see the obs README on device labels)
+                logits, cache = self._chunk_jit(self.params, cache,
+                                                *chunk_in)
+            c_chunks.inc()
+            n_chunks += 1
+            slot.prefill_pos += ln
+            if slot.prefilling:
+                return
+            with tracer.span("prefill.fetch"):
+                row = np.asarray(logits[0, ln - 1])
+            prefillq.popleft()
+            self.prefill_calls += 1
+            reg.counter("serve.prefill_calls").inc()
+            if grow:
+                # rows are on device now — publish the prompt's pages for
+                # later prefix matches
+                with tracer.span("kv.prefix"):
+                    alloc.register_chain_prefix(
+                        req.uid, prefix_keys(req.prompt, ps))
+            with tracer.span("sample"):
+                first = sample_token(row, req.sampling, slot.rng)
+                sched.record_token(slot, first, now=time.perf_counter() - t0)
+                tokens[slot.index, 0] = first
+                pos[slot.index] = slot.pos  # == len(prompt)
+
+        with use_rules(self.rules):
+            n_steps = 0
+            t0 = time.perf_counter()
+            while sched.has_work:
+                with tracer.span("serve.iteration"):
+                    if paranoid:
+                        alloc.check()
+                    with tracer.span("schedule.admit"):
+                        admitted = sched.admit(now=time.perf_counter() - t0,
+                                               chunked=True)
+                        for slot in admitted:
+                            self._sparsity.reset_row(slot.index)
+                            geo.set_chain(tables, slot.index,
+                                          alloc.chain(slot.request.uid))
+                            prefillq.append(slot)
+                        max_concurrent = max(max_concurrent,
+                                             len(sched.active_slots()))
+                    # ONE chunk per iteration: prefill progress is
+                    # interleaved with decode so in-flight slots keep
+                    # emitting tokens.
+                    if prefillq:
+                        _prefill_next_chunk()
+                    # budget-1 requests finish at prefill
+                    with tracer.span("retire"):
+                        for slot in sched.retire_done(
+                                now=time.perf_counter() - t0):
+                            geo.clear_chain(tables, slot.index)
+                    if grow:
+                        with tracer.span("kv.grow"):
+                            _grow()
+                    active = sched.decoding_slots()
+                    g_queue.set(len(sched.queue))
+                    g_active.set(len(active))
+                    g_occ.set(len(active) / self.n_slots)
+                    if not active:
+                        continue
+                    with tracer.span("kv.tables"):
+                        # Null the page-table rows of slots sitting this step
+                        # out (free, or mid-prefill): their stale token/pos
+                        # rows still ride the batch, but their writes sink to
+                        # the null page.
+                        step_tables = tables.copy()
+                        decoding = {s.index for s in active}
+                        for i in range(self.n_slots):
+                            if i not in decoding:
+                                step_tables[i, :] = NULL_PAGE
+                    obs_ctx = (observe_dispatch(self._dispatch.on_event)
+                               if tel.enabled and not self._dispatch.sealed
+                               else contextlib.nullcontext())
+                    probed = probe_every > 0 and n_steps % probe_every == 0
+                    t_step = time.perf_counter()
+                    with tracer.span("decode.step", probed=probed), obs_ctx:
+                        with tracer.span("decode.inputs"):
+                            step_in = ({"tokens": jnp.asarray(tokens)},
+                                       jnp.asarray(pos),
+                                       jnp.asarray(step_tables))
+                        # the jit call sits directly in decode.step, with
+                        # no child span open
+                        if probed:
+                            logits, cache, sp_aux = self._step_paged_probed(
+                                self.params, cache, *step_in)
+                        else:
+                            logits, cache = self._step_paged(
+                                self.params, cache, *step_in)
+                        with tracer.span("decode.fetch"):
+                            logits = np.asarray(logits)
+                    self._dispatch.seal()
+                    dt_step = time.perf_counter() - t_step
+                    h_step.observe(dt_step)
+                    h_step_recent.observe(dt_step)
+                    c_steps.inc()
+                    n_steps += 1
                     if probed:
-                        logits, cache, sp_aux = self._step_paged_probed(
-                            self.params, cache, *step_in)
-                    else:
-                        logits, cache = self._step_paged(
-                            self.params, cache, *step_in)
-                    logits = np.asarray(logits)
-                self._dispatch.seal()
-                dt_step = time.perf_counter() - t_step
-                h_step.observe(dt_step)
-                h_step_recent.observe(dt_step)
-                c_steps.inc()
-                n_steps += 1
-                if probed:
-                    self._sparsity.update(
-                        sp_aux, self._sparsity_meta,
-                        active_rows=[s.index for s in active])
-                now = time.perf_counter() - t0
-                with tracer.span("sample"):
-                    for slot in active:
-                        nxt = sample_token(logits[slot.index],
-                                           slot.request.sampling, slot.rng)
-                        sched.record_token(slot, nxt, now=now)
-                        tokens[slot.index, 0] = nxt
-                        slot.pos += 1
-                        pos[slot.index] = slot.pos
-                for slot in sched.retire_done(now=time.perf_counter() - t0):
-                    geo.clear_chain(tables, slot.index)
+                        self._sparsity.update(
+                            sp_aux, self._sparsity_meta,
+                            active_rows=[s.index for s in active])
+                    now = time.perf_counter() - t0
+                    with tracer.span("sample"):
+                        for slot in active:
+                            nxt = sample_token(logits[slot.index],
+                                               slot.request.sampling, slot.rng)
+                            sched.record_token(slot, nxt, now=now)
+                            tokens[slot.index, 0] = nxt
+                            slot.pos += 1
+                            pos[slot.index] = slot.pos
+                    with tracer.span("retire"):
+                        for slot in sched.retire_done(
+                                now=time.perf_counter() - t0):
+                            geo.clear_chain(tables, slot.index)
             dt = time.perf_counter() - t0
-        alloc.check()
-        if alloc.used_pages:
-            raise RuntimeError(f"{alloc.used_pages} KV pages still held "
-                               "after the queue drained")
-        total = sum(len(v) for v in sched.finished.values())
-        stats = {
-            "wall_s": dt,
-            "tok_s": total / dt if dt else float("inf"),
-            "decode_steps": n_steps,
-            "prefill_calls": self.prefill_calls,
-            "prefill_chunks": n_chunks,
-            "pages_capacity": alloc.capacity,
-            "page_size": geo.page_size,
-            "kv_policy": self.kv_policy,
-            "max_concurrent": max_concurrent,
-            "preemptions": sched.preemption_count,
-            "prefix_hit_pages": sched.prefix_hit_pages,
-            "cow_copies": n_cow,
-            "cow_in_place": n_cow_inplace,
-            "grown_pages": n_grown,
-            "ttft_s": dict(sched.ttft),
-        }
-        if tel.enabled:
-            tel.emit({"kind": "snapshot",
-                      "metrics": self.metrics_snapshot()})
+        with tracer.span("serve.drain"):
+            alloc.check()
+            if alloc.used_pages:
+                raise RuntimeError(f"{alloc.used_pages} KV pages still "
+                                   "held after the queue drained")
+            total = sum(len(v) for v in sched.finished.values())
+            stats = {
+                "wall_s": dt,
+                "tok_s": total / dt if dt else float("inf"),
+                "decode_steps": n_steps,
+                "prefill_calls": self.prefill_calls,
+                "prefill_chunks": n_chunks,
+                "pages_capacity": alloc.capacity,
+                "page_size": geo.page_size,
+                "kv_policy": self.kv_policy,
+                "max_concurrent": max_concurrent,
+                "preemptions": sched.preemption_count,
+                "prefix_hit_pages": sched.prefix_hit_pages,
+                "cow_copies": n_cow,
+                "cow_in_place": n_cow_inplace,
+                "grown_pages": n_grown,
+                "ttft_s": dict(sched.ttft),
+            }
+            if tel.enabled:
+                tel.emit({"kind": "snapshot",
+                          "metrics": self.metrics_snapshot()})
         return sched.finished, stats
 
     # -- telemetry read side -------------------------------------------------
@@ -747,11 +782,9 @@ class Engine:
           silently dropped;
         * ``sparsity`` — per-layer realized k/N and cross-step winner
           overlap from the probed decode steps, plus the staged
-          execution-path attribution (topk/hadamard/dense × backend,
-          est. FLOP shares, est. sparse-vs-dense decode time split).
+          execution paths (topk/hadamard/dense × backend, sites each).
         """
         stages = self.telemetry.tracer.totals()
-        decode_total = stages.get("decode.step", {}).get("total_s")
         requests = {}
         if self._last_sched is not None:
             requests = {uid: rec.to_event()
@@ -763,7 +796,7 @@ class Engine:
             "requests": requests,
             "sparsity": {
                 "layers": self._sparsity.summary(),
-                "paths": self._dispatch.summary(decode_total),
+                "paths": self._dispatch.summary(),
                 "probe_steps": self._sparsity.probes,
             },
         }

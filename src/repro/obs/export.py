@@ -172,7 +172,7 @@ def latency_columns(snapshot: Dict) -> Dict:
 
 def sparsity_columns(snapshot: Dict) -> Dict:
     """Realized-sparsity columns: mean realized k/N and winner overlap
-    across layers, plus the estimated sparse-path share of decode time."""
+    across layers."""
     cols: Dict = {}
     layers = snapshot.get("sparsity", {}).get("layers", {})
     rk = [e["realized_k_frac"] for e in layers.values()
@@ -183,9 +183,6 @@ def sparsity_columns(snapshot: Dict) -> Dict:
         cols["realized_k_frac"] = round(sum(rk) / len(rk), 4)
     if ov:
         cols["winner_overlap"] = round(sum(ov) / len(ov), 4)
-    paths = snapshot.get("sparsity", {}).get("paths", {})
-    if "sparse_flop_frac_est" in paths:
-        cols["sparse_flop_frac_est"] = paths["sparse_flop_frac_est"]
     return cols
 
 

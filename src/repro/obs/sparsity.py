@@ -22,14 +22,9 @@ structure; this module measures what actually flows through it:
   overlap per layer (|support_t ∩ support_{t-1}| / K per slot, reset on
   request admission).
 * **DispatchStats** — trace-time execution-path attribution fed by the
-  observer hook in :mod:`repro.core.api`: which path (topk / hadamard /
-  dense) and backend (pallas / interpret / jnp) each CS layer staged,
-  with the kernel cost model (FLOPs = 2·B·K·D_out for the sparse-sparse
-  contraction — see ``kernels/topk_gather.py``) and the per-grid-step
-  VMEM estimate from :mod:`repro.kernels.block_validation`.  Combined
-  with the measured decode stage time this yields the estimated fraction
-  of decode wall-time inside the sparse kernel path vs the dense
-  fallback (an estimate: one jit can't be timed from inside).
+  observer hook in :mod:`repro.core.api`: how many CS layer sites each
+  path (topk / hadamard / dense) and backend (pallas / interpret / jnp)
+  staged.  Counts only; time per kernel comes from a device trace.
 
 No module here imports :mod:`repro.core` or :mod:`repro.models` — the
 hooks point the other way, so the capture can be active while those
@@ -47,7 +42,7 @@ import numpy as np
 __all__ = ["SupportCapture", "capture_supports", "observe_site",
            "observe_support", "observe_activation", "drain_pending",
            "emit_stacked", "capture_active", "SparsityStats",
-           "DispatchStats", "est_path_flops"]
+           "DispatchStats"]
 
 
 # ---------------------------------------------------------------------------
@@ -309,38 +304,11 @@ class SparsityStats:
 # Execution-path attribution (trace-time, fed by repro.core.api hook)
 # ---------------------------------------------------------------------------
 
-def est_path_flops(ev: Dict) -> float:
-    """Cost model per staged CS layer application (see module docstring)."""
-    b, d_in, d_out = ev["batch"], ev["d_in"], ev["d_out"]
-    if ev["path"] == "topk":
-        return 2.0 * b * ev.get("k", d_in) * d_out
-    if ev["path"] == "dense":
-        return 2.0 * b * d_in * d_out
-    return 2.0 * b * d_in * d_out / max(1, ev.get("n", 1))  # hadamard
-
-
-def _est_topk_vmem(ev: Dict) -> int:
-    """Per-grid-step VMEM estimate for the topk_gather kernel's resident
-    blocks under its default (nG, B) grid with block_g = G (matches the
-    BlockSpecs in ``kernels/topk_gather.py``), via the shared estimator in
-    ``kernels/block_validation``."""
-    from repro.kernels.block_validation import estimate_vmem_bytes
-    n = max(1, ev.get("n", 1))
-    k = ev.get("k", ev["d_in"])
-    g, p = ev["d_out"] // n, ev["d_in"] // n
-    return estimate_vmem_bytes([
-        ((1, k), np.float32), ((1, k), np.int32), ((1, k), np.int32),
-        ((p, g, n), np.float32), ((p, g, n), np.int8),
-        ((1, g * n), np.float32),
-    ])
-
-
 class DispatchStats:
     """Records the execution-path decision of every CS layer staged while
     unsealed (the engine seals after the first decode-step trace, so the
-    site list describes exactly one staged decode step; ``lax.scan``
-    bodies count once — shares are unaffected when all sparse layers live
-    in the unit scan, which is the repro's layout)."""
+    site list describes exactly one staged decode step; a ``lax.scan``
+    body counts once, however many units it runs)."""
 
     def __init__(self):
         self.sites: List[Dict] = []
@@ -360,33 +328,13 @@ class DispatchStats:
     def sealed(self) -> bool:
         return self._sealed
 
-    def summary(self, decode_total_s: Optional[float] = None) -> Dict:
-        """Aggregate by path+backend with est-FLOP shares; with a measured
-        decode stage total, also the estimated wall-time split."""
-        agg: Dict[str, Dict] = {}
-        total = 0.0
-        sparse = 0.0
+    def summary(self) -> Dict[str, int]:
+        """Staged sites per path and backend, e.g. ``{"topk[pallas]":
+        32}``."""
+        agg: Dict[str, int] = {}
         for ev in self.sites:
             backend = ("pallas-interpret" if ev.get("interpret")
                        else "pallas") if ev.get("pallas") else "jnp"
             key = f"{ev['path']}[{backend}]"
-            a = agg.setdefault(key, {"sites": 0, "est_flops": 0.0})
-            fl = est_path_flops(ev)
-            a["sites"] += 1
-            a["est_flops"] += fl
-            total += fl
-            if ev["path"] == "topk":
-                sparse += fl
-                if ev.get("pallas"):
-                    a.setdefault("est_vmem_bytes", 0)
-                    a["est_vmem_bytes"] += _est_topk_vmem(ev)
-        out: Dict = {"paths": agg}
-        if total > 0:
-            frac = sparse / total
-            out["sparse_flop_frac_est"] = round(frac, 6)
-            if decode_total_s is not None:
-                out["decode_sparse_time_est_s"] = round(
-                    frac * decode_total_s, 6)
-                out["decode_dense_time_est_s"] = round(
-                    (1.0 - frac) * decode_total_s, 6)
-        return out
+            agg[key] = agg.get(key, 0) + 1
+        return agg

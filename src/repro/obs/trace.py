@@ -10,8 +10,15 @@ lines in the schema :mod:`repro.obs.export` validates.
 The stack is thread-local, so spans opened on different threads nest
 independently; per-stage totals (``totals()``) aggregate across threads.
 
+An enabled tracer's spans are also profiler annotations
+(``jax.profiler.TraceAnnotation`` under the bare span name), so a
+``jax.profiler`` trace of the program holds them on the device's clock:
+an idle gap of the chip can be put down to the span the host was in.
+Outside a trace an annotation costs about 0.5 µs (TPU v5e host).
+
 Disabled tracers are zero-cost: ``span()`` returns one shared re-entrant
-null context manager — no allocation, no clock read, no event.
+null context manager — no allocation, no clock read, no event, no
+annotation.
 """
 
 from __future__ import annotations
@@ -59,9 +66,21 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``, imported at the first
+    enabled span so that this module loads without JAX."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
+
 
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Optional[dict]):
         self._tracer = tracer
@@ -70,11 +89,14 @@ class _Span:
 
     def __enter__(self):
         self._tracer._push(self.name)
+        self._note = _annotation(self.name)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
+        self._note.__exit__(None, None, None)
         self._tracer._pop(self, dur)
         return False
 
@@ -126,6 +148,11 @@ class Tracer:
             self.sink.write(ev.to_dict())
 
     # -- read side ----------------------------------------------------------
+    def current(self) -> Optional[str]:
+        """The innermost span open on this thread, None outside any."""
+        stack = self._stack.names
+        return stack[-1] if stack else None
+
     def totals(self) -> Dict[str, Dict[str, float]]:
         """Per-span-name aggregate: total seconds + completed-span count."""
         with self._lock:
