@@ -67,18 +67,19 @@ def family_selects(sp: SparsityConfig, n_tokens: int, d_in: int,
     """Selects staged by one kwta→packed-projection pipeline.
 
     Mirrors ``apply_kwta`` + ``packed_linear_apply``: the k-WTA stages a
-    ``top_k`` unless it runs the histogram/bisection datapath; the
-    downstream projection re-derives the support (one more ``top_k``)
-    only on the topk path when no ``(vals, idx)`` handoff exists — the
-    handoff exists only for the exact global top-k impl."""
+    ``top_k`` only for the exact global impl (``kwta_support``, whose
+    ``(vals, idx)`` handoff it is); histogram, bisection and local k-WTA
+    (the sort-free :func:`repro.core.kwta.kwta` per partition) stage none.
+    The downstream projection re-derives the support (one more ``top_k``)
+    only on the topk path when no handoff exists."""
     if not sp.activation_sparse:
         return 0
     k = sp.k_for(d_in)
     if k >= d_in:
         return 0
-    kwta_runs_topk = sp.kwta_impl not in ("hist", "bisect")
-    has_support = kwta_runs_topk and sp.kwta_partitions <= 1
-    n_sel = 1 if kwta_runs_topk else 0
+    has_support = (sp.kwta_impl not in ("hist", "bisect")
+                   and sp.kwta_partitions <= 1)
+    n_sel = 1 if has_support else 0
     if family_path(sp, n_tokens, d_in, d_out) == "topk" and not has_support:
         n_sel += 1
     return n_sel

@@ -11,6 +11,11 @@ assert exactly one top_k was staged out per sparse layer:
         jax.make_jaxpr(fn)(x)
     assert c.top_k == 1
 
+A sort-free Select (exact k-WTA by radix select, :func:`repro.core.kwta.kwta`)
+stages under :func:`staged_select` and ticks a count of its own,
+``c.counts["threshold"]``, so a test can see which implementation each
+site staged.
+
 Counters tick at *trace* time — inside ``lax.scan`` bodies they count once
 per traced superblock, and jit cache hits don't tick them (use
 ``jax.make_jaxpr`` or a fresh function to force a trace when asserting).
@@ -36,7 +41,7 @@ class SelectCounter:
     """Per-``with``-block Select counts (see :func:`count_selects`)."""
 
     def __init__(self) -> None:
-        self.counts = {"top_k": 0}
+        self.counts = {"top_k": 0, "threshold": 0}
 
     @property
     def top_k(self) -> int:
@@ -75,18 +80,23 @@ def count_selects() -> Iterator[SelectCounter]:
         _STATE.stack.remove(c)
 
 
-def counted_top_k(x, k: int):
-    """``lax.top_k`` that ticks every active Select counter (trace-time).
-
-    Staged under a ``select`` name scope so the jaxpr-level Select-count
-    rule (:mod:`repro.analysis`) can attribute each ``top_k`` primitive to
-    the enclosing layer scope.
-    """
+@contextlib.contextmanager
+def staged_select(kind: str) -> Iterator[None]:
+    """Tick every active Select counter's ``kind`` count (trace-time) and
+    stage the block under a ``select`` name scope, so the jaxpr-level
+    Select-count rule (:mod:`repro.analysis`) can attribute what it stages
+    to the enclosing layer scope."""
     import jax
     for c in _STATE.stack:
-        c.counts["top_k"] += 1
-    _STATE.legacy.counts["top_k"] += 1
+        c.counts[kind] += 1
+    _STATE.legacy.counts[kind] += 1
     with jax.named_scope("select"):
+        yield
+
+
+def counted_top_k(x, k: int):
+    """``lax.top_k`` that ticks every active Select counter (trace-time)."""
+    with staged_select("top_k"):
         return lax.top_k(x, k)
 
 

@@ -2,20 +2,29 @@
 
 k-WTA replaces ReLU: exactly the K largest pre-activations propagate, the
 rest are zeroed (winners keep their values).  Gradients flow only through
-winners (this falls out of the scatter/gather formulation automatically —
-straight-through on the support, zero elsewhere, matching [Ahmad &
+winners (straight-through on the support, zero elsewhere, matching [Ahmad &
 Scheinkman 2019]).
 
-Three implementations:
+Implementations, and where each runs:
 
-* :func:`kwta` — exact top-k via ``lax.top_k`` + scatter. The reference
-  semantics and the training default.
+* :func:`kwta` — exact k-WTA, sort-free and scatter-free: a radix select of
+  the K-th largest value over an order-preserving int32 key, then the
+  lowest-index ties up to K (``lax.top_k``'s tie rule), then one masked
+  write (:func:`topk_keep`).  On a TPU it runs as the ``kwta_exact``
+  Pallas kernel (one HBM read and one write per row block); elsewhere the
+  same formula runs in ``jnp``.  The reference semantics and the training
+  default; :func:`kwta_channel` and :func:`kwta_local` reach it.
+* :func:`kwta_support` — exact top-k via ``lax.top_k`` + scatter that also
+  returns the ``(vals, idx)`` winner support, for the sparse-activation
+  handoff to the next layer's ``topk_gather``.
 * :func:`kwta_hist` — the paper's **histogram-threshold global k-WTA**
   (Fig. 10): build a value histogram, walk it from the top bin to find the
   smallest threshold retaining >= K values, keep everything above it.  Exact
   for quantized inputs with distinct bins; for continuous inputs may retain
   slightly more than K on bin ties (the paper's hardware has the same
   behavior — threshold compare, not an exact sort).
+* :func:`kwta_bisect` — threshold k-WTA by bisection on the value axis
+  (>= K kept on ties), the SPMD-native form the serving FFN uses.
 * :func:`kwta_local` — the paper's **local/partitioned k-WTA** (used after
   conv layers; competition within partitions).  On TPU we align partitions
   with the tensor-parallel shard so winner selection never crosses chips
@@ -26,16 +35,86 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from .instrument import counted_top_k
+from .instrument import counted_top_k, staged_select
+
+_INT32_MIN = -2 ** 31
+
+
+def order_key(x: jax.Array) -> jax.Array:
+    """Order-preserving int32 key of ``x``'s float32 values.
+
+    Compared as signed ints, keys order as the floats do; -0.0 and 0.0 share
+    the key 0, since they compare equal."""
+    x32 = x.astype(jnp.float32)
+    bits = lax.bitcast_convert_type(x32, jnp.int32)
+    key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+    return jnp.where(x32 == 0, 0, key)
+
+
+def topk_keep(key: jax.Array, lane: jax.Array, k: int, d: int, count):
+    """Winner mask of exact top-k over rows of ``d`` keys, without a sort.
+
+    ``lane`` is each key's position in its row; ``count(mask)`` gives each
+    row's number of True entries, broadcastable against ``key``.  Radix
+    select builds the K-th largest key ``t`` bit by bit (32 rounds of
+    compare-and-count); every key above ``t`` wins, and among the keys
+    equal to ``t`` the lowest positions fill the remaining places, found by
+    a second radix select over the reversed position (``lax.top_k``'s tie
+    rule).  Exactly K winners per row."""
+    one = jnp.int32(1)
+
+    def key_round(i, t):
+        c = t | (one << (30 - i))
+        return jnp.where(count(key >= c) >= k, c, t)
+
+    # Bit 31 of the unsigned key is the sign: candidate 0 in signed terms.
+    t = jnp.where(count(key >= 0) >= k, 0, jnp.int32(_INT32_MIN))
+    t = lax.fori_loop(0, 31, key_round, t)
+    gt, eq = key > t, key == t
+    need = k - count(gt)                   # >= 1 ties still to take
+    rank = (d - 1) - lane                  # higher for lower positions
+    bits = max(1, (d - 1).bit_length())
+
+    def tie_round(i, u):
+        c = u | (one << (bits - 1 - i))
+        return jnp.where(count(eq & (rank >= c)) >= need, c, u)
+
+    u = lax.fori_loop(0, bits, tie_round, jnp.zeros_like(t))
+    return gt | (eq & (rank >= u))
+
+
+def kwta_exact_jnp(x: jax.Array, k: int) -> jax.Array:
+    """:func:`topk_keep` over the last axis in plain ``jnp`` (off-TPU)."""
+    d = x.shape[-1]
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    keep = topk_keep(order_key(x), lane, k, d,
+                     lambda m: jnp.sum(m, axis=-1, keepdims=True,
+                                       dtype=jnp.int32))
+    return jnp.where(keep, x, jnp.zeros_like(x))
 
 
 def kwta(x: jax.Array, k: int, axis: int = -1) -> jax.Array:
-    """Exact k-WTA: keep the K largest values along ``axis``, zero the rest."""
+    """Exact k-WTA: keep the K largest values along ``axis``, zero the rest.
+
+    Ties go to the lower index, as with ``lax.top_k``.  Sort-free: the
+    ``kwta_exact`` Pallas kernel on a TPU, :func:`kwta_exact_jnp` elsewhere.
+    """
     if k <= 0:
         raise ValueError(f"k must be positive, got {k}")
     x_m = jnp.moveaxis(x, axis, -1)
-    y, _ = kwta_support(x_m, k)
+    d = x_m.shape[-1]
+    if k >= d:
+        return x
+    with staged_select("threshold"):
+        if jax.default_backend() == "tpu":
+            # deferred import: kernels.ops imports repro.core at module scope
+            from repro.kernels.ops import kwta_exact_lastaxis
+
+            y = kwta_exact_lastaxis(x_m, k)
+        else:
+            y = kwta_exact_jnp(x_m, k)
     return jnp.moveaxis(y, -1, axis)
 
 

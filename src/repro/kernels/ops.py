@@ -18,7 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import functional as F
+from .block_validation import LANES
 from .grouped_cs_matmul import grouped_cs_matmul
+from .kwta_exact import kwta_exact_pallas
 from .kwta_hist import kwta_hist_pallas
 from .packed_matmul import packed_matmul, to_partition_major
 from .ref import ref_kwta_hist
@@ -184,3 +186,46 @@ def _kh_bwd(k, interpret, mask, dy):
 
 
 kwta_hist_op.defvjp(_kh_fwd, _kh_bwd)
+
+
+# ---------------------------------------------------------------------------
+# exact k-WTA op (straight-through gradient on the K winners)
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def kwta_exact_op(x, k: int, interpret: bool = False):
+    """Exact k-WTA along axis 1 of (G, D, N) via the Pallas kernel."""
+    return kwta_exact_pallas(x, k, interpret=interpret)
+
+
+def _ke_fwd(x, k, interpret):
+    # The residual is the winner mask itself, not ``y != 0``: after a ReLU
+    # a winner may hold 0 and still takes its gradient.
+    return kwta_exact_pallas(x, k, with_mask=True, interpret=interpret)
+
+
+def _ke_bwd(k, interpret, keep, dy):
+    return (dy * keep.astype(dy.dtype),)
+
+
+kwta_exact_op.defvjp(_ke_fwd, _ke_bwd)
+
+
+def kwta_exact_lastaxis(x, k: int, interpret: bool = False):
+    """Exact k-WTA over the last axis of ``x`` through :func:`kwta_exact_op`.
+
+    With a leading axis N of at least ``LANES // 2`` (the batch of a conv
+    activation (B, H, W, C)) the kernel's view is (H*W, C, B): XLA keeps
+    such an activation batch-minor, so both transposes are bitcasts, and
+    padding N to whole lanes at most doubles the kernel's work.  A shorter
+    leading axis would leave most lanes padding (127 of 128 at batch 1), so
+    there every row goes along lanes, (1, D, R), for one transposing copy
+    each way."""
+    d = x.shape[-1]
+    n = x.shape[0] if x.ndim > 1 else 1
+    if 2 * n >= LANES:
+        x3 = x.reshape(n, -1, d).transpose(1, 2, 0)
+        y3 = kwta_exact_op(x3, k, interpret)
+        return y3.transpose(2, 0, 1).reshape(x.shape)
+    y3 = kwta_exact_op(x.reshape(-1, d).T[None], k, interpret)
+    return y3[0].T.reshape(x.shape)

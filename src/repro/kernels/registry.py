@@ -84,9 +84,20 @@ KWTA_HIST_SWEEP = (
 )
 
 
+#: kwta_exact_pallas: (g, d, n, k) — the GSC network's three k-WTA sites
+#: at batch n = 1024: conv1's and conv2's channels (H*W groups of 64) and
+#: the linear layer.
+KWTA_EXACT_SWEEP = (
+    (784, 64, 1024, 8),     # conv1, 28x28
+    (100, 64, 1024, 8),     # conv2, 10x10
+    (1, 1504, 1024, 180),   # linear
+)
+
+
 def kernel_cases() -> List[KernelCase]:
     """Every shipped kernel × shape configuration, as staging recipes."""
     from .grouped_cs_matmul import grouped_cs_matmul
+    from .kwta_exact import kwta_exact_pallas
     from .kwta_hist import kwta_hist_pallas
     from .packed_matmul import packed_matmul
     from .topk_gather import topk_gather_matmul
@@ -130,6 +141,13 @@ def kernel_cases() -> List[KernelCase]:
                 kwta_hist_pallas, _sds((b, d), jnp.float32),
                 k=k, block_b=bb)))
 
+    for g, d, n, k in KWTA_EXACT_SWEEP:
+        cases.append(KernelCase(
+            "kwta_exact",
+            f"kwta_exact[g{g} d{d} n{n} k{k}]",
+            lambda g=g, d=d, n=n, k=k: _trace(
+                kwta_exact_pallas, _sds((g, d, n), jnp.float32), k=k)))
+
     return cases
 
 
@@ -163,5 +181,5 @@ def ensure_provenance() -> None:
         return {1: Interval(0, refs[3].block_shape[0] - 1)}   # pidx_ref
 
     register_value_ranges("_topk_gather_kernel", topk_gather_ranges)
-    # grouped_cs / packed_matmul / kwta_hist index only with program_id
-    # affine forms and static slices — no declared ranges needed.
+    # grouped_cs / packed_matmul / kwta_hist / kwta_exact index only with
+    # program_id affine forms and static slices — no declared ranges needed.

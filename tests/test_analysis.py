@@ -101,10 +101,11 @@ def test_family_selects_mirrors_dispatch():
     # exact global top-k: one Select, support handed off (no re-derive).
     assert family_selects(SparsityConfig(kwta_impl="topk", **base),
                           4, 128, 64) == 1
-    # local k-WTA has no handoff form: its Select + the re-derivation.
+    # local k-WTA is sort-free and has no handoff form: only the
+    # re-derivation stages a Select.
     assert family_selects(SparsityConfig(kwta_impl="topk",
                                          kwta_partitions=2, **base),
-                          4, 128, 64) == 2
+                          4, 128, 64) == 1
     # dense activations: nothing to Select.
     assert family_selects(SparsityConfig(n=4), 4, 128, 64) == 0
 

@@ -300,7 +300,7 @@ def test_scratch_within_budget_is_clean():
 def test_registry_covers_all_four_kernels():
     kinds = {c.kernel for c in kernel_cases()}
     assert kinds == {"topk_gather", "grouped_cs_matmul", "packed_matmul",
-                     "kwta_hist"}
+                     "kwta_hist", "kwta_exact"}
 
 
 def test_lint_kernels_sweep_is_clean():

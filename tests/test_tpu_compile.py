@@ -17,7 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import kwta_hist_pallas, topk_gather_matmul
+from repro.kernels import (kwta_exact_pallas, kwta_hist_pallas,
+                           topk_gather_matmul)
 
 
 @pytest.fixture(scope="module")
@@ -68,4 +69,13 @@ def test_kwta_hist_compiles_at_smollm_width(one_chip):
     x = jax.ShapeDtypeStruct((8, d_ff), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda x: kwta_hist_pallas(x, d_ff // 8)).lower(
         x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("g,d,k", [(784, 64, 8), (1, 1504, 180)])
+def test_kwta_exact_compiles_at_gsc_sites(one_chip, g, d, k):
+    """conv1's channel k-WTA at batch 1024 (802816 rows of 64), and the
+    linear layer's (1024 rows of 1504)."""
+    x = jax.ShapeDtypeStruct((g, d, 1024), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda x: kwta_exact_pallas(x, k)).lower(x).compile()
     assert "tpu_custom_call" in compiled.as_text()
