@@ -31,16 +31,35 @@ class ModelConfig:
     vocab_pad: int = 128             # pad vocab to a multiple (TPU lanes)
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # the router's width
     n_shared_experts: int = 0
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # Renormalise the top-k router weights to sum to 1 (Qwen3 does,
+    # DeepSeek-V2 does not).
+    norm_topk_prob: bool = True
+    # This chip's share of the routed experts: the contiguous range
+    # [held_expert_start, held_expert_start + held_experts); 0 holds all.
+    held_experts: int = 0
+    held_expert_start: int = 0
+    # Leading layers whose FFN is one dense SwiGLU of width dense_d_ff in
+    # place of the expert layer (DeepSeek's first_k_dense_replace).
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
 
     # --- MLA (DeepSeek) ---
     use_mla: bool = False
     kv_lora_rank: int = 0
     rope_head_dim: int = 64
+    mla_latent_norm: bool = False    # RMSNorm on the latent (kv_a_layernorm)
+    # YaRN rope scaling (0 = plain rope): factor, the original context,
+    # the ramp's fast/slow rotations, and the softmax mscale terms.
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM / hybrid ---
     # The repeating unit of block kinds; n_layers must be a multiple of its
@@ -83,10 +102,16 @@ class ModelConfig:
     head_pad: int = 0
 
     def __post_init__(self):
-        if self.n_layers % len(self.block_pattern):
+        if self.n_scanned_layers % len(self.block_pattern):
             raise ValueError(
-                f"{self.name}: n_layers={self.n_layers} not a multiple of "
+                f"{self.name}: n_layers={self.n_layers} less "
+                f"n_dense_layers={self.n_dense_layers} not a multiple of "
                 f"block_pattern length {len(self.block_pattern)}")
+        lo, n = self.held_expert_start, self.n_held_experts
+        if self.n_experts and not (0 <= lo and lo + n <= self.n_experts):
+            raise ValueError(
+                f"{self.name}: held experts [{lo}, {lo + n}) outside the "
+                f"router's {self.n_experts}")
 
     @property
     def head_dim(self) -> int:
@@ -105,18 +130,30 @@ class ModelConfig:
         return ((v + m - 1) // m) * m
 
     @property
+    def n_scanned_layers(self) -> int:
+        """Layers inside the scan (the leading dense layers are not)."""
+        return self.n_layers - self.n_dense_layers
+
+    @property
     def n_units(self) -> int:
         """Number of scan steps (superblocks)."""
-        return self.n_layers // len(self.block_pattern)
+        return self.n_scanned_layers // len(self.block_pattern)
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_held_experts(self) -> int:
+        return self.held_experts or self.n_experts
+
     def reduced(self, **overrides) -> "ModelConfig":
         """A small same-family config for CPU smoke tests."""
         base = dict(
-            n_layers=2 * len(self.block_pattern),
+            n_layers=2 * len(self.block_pattern) + min(self.n_dense_layers,
+                                                       1),
+            n_dense_layers=min(self.n_dense_layers, 1),
+            dense_d_ff=96 if self.n_dense_layers else 0,
             d_model=64,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2),
@@ -124,6 +161,10 @@ class ModelConfig:
             vocab_size=256,
             d_head=16,
             n_experts=min(self.n_experts, 4),
+            # a held share smaller than the router, where the config
+            # holds one, so the CPU tests exercise the cut
+            held_experts=2 if self.held_experts else 0,
+            held_expert_start=1 if self.held_experts else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
             experts_per_token=min(self.experts_per_token, 2),
             kv_lora_rank=32 if self.use_mla else 0,

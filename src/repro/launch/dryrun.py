@@ -363,8 +363,8 @@ def _accounting_decode(cfg, shape, mesh, rules, params_s, specs, cache_s,
         new_cache = {}
         for i, kind in enumerate(cfg.block_pattern):
             p = shared if kind == "shared_attn" else unit_params[f"b{i}"]
-            x, new_cache[f"b{i}"] = _block_decode(kind, p, x, cfg,
-                                                  unit_cache[f"b{i}"], pos)
+            x, new_cache[f"b{i}"], _ = _block_decode(
+                kind, p, x, cfg, unit_cache[f"b{i}"], pos)
         return x, new_cache
 
     compiled = jax.jit(unit_decode,

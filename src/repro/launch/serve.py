@@ -182,8 +182,13 @@ class Engine:
                                   else min(4 * self.kv_geo.page_size,
                                            self.kv_geo.view_len))
             self.kv_geo.chunk_spans(1, self.prefill_chunk)  # validates
+            # a config with experts also returns each row's routed pairs
+            # to held experts (the ``moe.*`` counters)
+            moe_counts = cfg.is_moe
+
             def decode_step_paged(p, c, b, pos, pg):
-                return T.serve_step(p, c, b, pos, cfg, pages=pg)
+                return T.serve_step(p, c, b, pos, cfg, pages=pg,
+                                    moe_counts=moe_counts)
 
             def prefill_chunk(p, c, toks, pg, start, ln):
                 return T.prefill_chunk(p, c, {"tokens": toks}, start, ln,
@@ -201,10 +206,10 @@ class Engine:
 
             def _probed_step_paged(p, c, b, pos, pg):
                 with obs_sparsity.capture_supports() as cap:
-                    logits, new_cache = T.serve_step(p, c, b, pos, cfg,
-                                                     pages=pg)
+                    out = T.serve_step(p, c, b, pos, cfg, pages=pg,
+                                       moe_counts=moe_counts)
                 self._sparsity_meta.update(cap.meta)
-                return logits, new_cache, cap.take_arrays()
+                return (*out, cap.take_arrays())
 
             self._step_paged_probed = jax.jit(_probed_step_paged,
                                               donate_argnums=(1,))
@@ -494,6 +499,11 @@ class Engine:
             c_chunks = reg.counter("serve.prefill_chunks")
             c_cow = reg.counter("serve.cow_copies")
             c_grow = reg.counter("serve.kv_grow_pages")
+            if self.cfg.is_moe:
+                c_held = reg.counter("moe.held_assignments")
+                c_moe_tokens = reg.counter("moe.tokens")
+                moe_layers = self.cfg.n_units * sum(
+                    k == "attn" for k in self.cfg.block_pattern)
             probe_every = tel.sparsity_every if tel.enabled else 0
             sched = Scheduler(self.n_slots, telemetry=tel, allocator=alloc,
                               kv_policy=self.kv_policy)
@@ -704,13 +714,19 @@ class Engine:
                         # the jit call sits directly in decode.step, with
                         # no child span open
                         if probed:
-                            logits, cache, sp_aux = self._step_paged_probed(
-                                self.params, cache, *step_in)
+                            logits, cache, *held, sp_aux = \
+                                self._step_paged_probed(self.params, cache,
+                                                        *step_in)
                         else:
-                            logits, cache = self._step_paged(
+                            logits, cache, *held = self._step_paged(
                                 self.params, cache, *step_in)
                         with tracer.span("decode.fetch"):
                             logits = np.asarray(logits)
+                            if held:
+                                rows = [s.index for s in active]
+                                c_held.inc(int(np.asarray(held[0])[rows]
+                                               .sum()))
+                                c_moe_tokens.inc(len(rows) * moe_layers)
                     self._dispatch.seal()
                     dt_step = time.perf_counter() - t_step
                     h_step.observe(dt_step)

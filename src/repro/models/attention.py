@@ -26,7 +26,8 @@ from repro.obs.sparsity import observe_site
 from repro.runtime.kvcache import paged_view, paged_write_chunk, \
     paged_write_rows
 from repro.sharding.context import constrain
-from .common import apply_rope, normal_init
+from .common import (apply_rope, normal_init, rmsnorm_apply, rmsnorm_init,
+                     yarn_freqs, yarn_mscale)
 
 
 def _proj_init(key, d_in, d_out, sp: SparsityConfig, out_axis, name_seed):
@@ -501,6 +502,12 @@ def gqa_chunk_prefill(params, x, cfg, cache, pages, pos_start, chunk_len):
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V2): latent KV compression
 # ---------------------------------------------------------------------------
+#
+# Rope convention: DeepSeek-V2 stores the rope columns of q and k_pe
+# interleaved and de-interleaves them before rotating halves; here they
+# are stored as halves.  The two differ by a fixed permutation of the
+# rope columns of the ``q`` and ``kpe`` weights, so under random weights
+# they are the same model; the benchmark's reference uses halves too.
 
 def mla_init(key, cfg):
     d, h, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
@@ -517,18 +524,46 @@ def mla_init(key, cfg):
     specs = {"q": (None, "heads"), "dkv": (None, None), "kpe": (None, None),
              "uk": (None, "heads"), "uv": (None, "heads"),
              "o": ("heads", None)}
+    if cfg.mla_latent_norm:
+        params["kv_norm"], specs["kv_norm"] = rmsnorm_init(r)
     return params, specs
 
 
+def _mla_rope(x, positions, cfg):
+    """Rope on the ``rope_head_dim`` columns, with YaRN's frequencies and
+    cos/sin scale when ``cfg.yarn_factor`` is set."""
+    if not cfg.yarn_factor:
+        return apply_rope(x, positions, cfg.rope_theta)
+    freqs = yarn_freqs(cfg.rope_head_dim, cfg.rope_theta, cfg.yarn_factor,
+                       cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                       cfg.yarn_beta_slow)
+    scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return apply_rope(x, positions, cfg.rope_theta, freqs=jnp.asarray(freqs),
+                      scale=scale)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope head size)^-1/2, times YaRN's mscale squared where
+    ``yarn_mscale_all_dim`` is set (as DeepSeek-V2 scales its softmax)."""
+    scale = 1.0 / np.sqrt(cfg.head_dim + cfg.rope_head_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkv(params, x, cfg, positions):
+    """Queries split into their nope and rope parts, the latent rows (after
+    the latent norm, as cached) and the roped shared key rows."""
     h, dh, dr = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim
     ct = x.dtype
     q = (x @ params["q"].astype(ct)).reshape(*x.shape[:-1], h, dh + dr)
-    q_nope, q_pe = q[..., :dh], q[..., dh:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_nope, q_pe = q[..., :dh], _mla_rope(q[..., dh:], positions, cfg)
     c_kv = x @ params["dkv"].astype(ct)                      # (B, S, r)
-    k_pe = apply_rope(x @ params["kpe"].astype(ct), positions,
-                      cfg.rope_theta)                        # (B, S, dr)
+    if "kv_norm" in params:
+        c_kv = rmsnorm_apply(params["kv_norm"], c_kv, cfg.norm_eps)
+    k_pe = _mla_rope(x @ params["kpe"].astype(ct), positions,
+                     cfg)                                    # (B, S, dr)
     return q_nope, q_pe, c_kv, k_pe
 
 
@@ -540,8 +575,9 @@ def _mla_expand(params, c_kv, cfg, ct):
 
 
 def _mla_forward(params, x, cfg, positions):
-    """Full causal MLA forward. Returns (y, c_kv, k_pe) — the latent rows
-    the decode cache stores (fused-prefill bulk write)."""
+    """Full causal MLA forward in the expanded form (K and V up-projected
+    from the latent).  Returns (y, c_kv, k_pe) — the latent rows the
+    decode cache stores (fused-prefill bulk write)."""
     h, dh, dr = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim
     q_nope, q_pe, c_kv, k_pe = _mla_qkv(params, x, cfg, positions)
     k_nope, v = _mla_expand(params, c_kv, cfg, x.dtype)
@@ -549,7 +585,7 @@ def _mla_forward(params, x, cfg, positions):
     k = jnp.concatenate([k_nope,
                          jnp.broadcast_to(k_pe[..., None, :],
                                           (*k_pe.shape[:-1], h, dr))], axis=-1)
-    scale = 1.0 / np.sqrt(dh + dr)
+    scale = mla_softmax_scale(cfg)
     if x.shape[1] > cfg.flash_block:
         out = _flash_attn(q, k, v, scale, cfg.flash_block,
                           unroll=cfg.unroll_inner)
@@ -582,22 +618,31 @@ def mla_prefill(params, x, cfg, positions, max_seq: int):
 
 
 def _mla_cache_attn(params, x, q_nope, q_pe, ckv_view, kpe_view, valid, cfg):
-    """MLA attention over full-length latent-cache views with a
+    """MLA attention over latent-cache views in the absorbed form, with a
     broadcastable validity mask ``valid`` (B|1, S_q|1, V) — the shared
-    tail of the decode step and the chunked-prefill step."""
-    h, dh, dr = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim
-    k_nope, v = _mla_expand(params, ckv_view, cfg, x.dtype)
-    q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    k = jnp.concatenate([k_nope,
-                         jnp.broadcast_to(kpe_view[..., None, :],
-                                          (*kpe_view.shape[:-1], h, dr))],
-                        axis=-1)
-    scale = 1.0 / np.sqrt(dh + dr)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    scores = jnp.where(valid[:, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    return out.reshape(*x.shape[:-1], h * dh) @ params["o"].astype(x.dtype)
+    tail of the decode step and the chunked-prefill step.
+
+    ``uk`` is folded into the query (one latent query per head) and
+    ``uv`` into the output, so the cached latent rows are read as they
+    are and never up-projected: per query row and head, the scores are
+    taken against the ``r + dr`` wide rows and the probabilities weight
+    the latent rows, whose mix is up-projected once."""
+    h, dh, r = cfg.n_heads, cfg.head_dim, cfg.kv_lora_rank
+    ct = x.dtype
+    with jax.named_scope("mla.attn"):
+        uk = params["uk"].astype(ct).reshape(r, h, dh)
+        uv = params["uv"].astype(ct).reshape(r, h, dh)
+        q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, uk)
+        scores = (jnp.einsum("bqhr,bkr->bhqk", q_lat, ckv_view,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q_pe, kpe_view,
+                               preferred_element_type=jnp.float32))
+        scores = jnp.where(valid[:, None], scores * mla_softmax_scale(cfg),
+                           -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(ct)
+        ctx = jnp.einsum("bhqk,bkr->bqhr", probs, ckv_view)
+        out = jnp.einsum("bqhr,rhd->bqhd", ctx, uv)
+    return out.reshape(*x.shape[:-1], h * dh) @ params["o"].astype(ct)
 
 
 def mla_decode(params, x, cfg, cache, pos, pages=None):

@@ -15,6 +15,7 @@ axis names (resolved to mesh axes by repro.sharding).  Logical axes used:
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -77,14 +78,49 @@ def rope_freqs(d_head: int, theta: float) -> jax.Array:
                             / d_head))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, Dh) or (..., S, Dh); positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term (DeepSeek-V2's
+    ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(d_head: int, theta: float, factor: float, original: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies, as DeepSeek-V2's YaRN rotary embedding
+    computes them: the fast-rotating dimensions keep ``theta``'s
+    frequencies, the slow ones are divided by ``factor``, with a linear
+    ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
+    times over the ``original`` context."""
+    def dim_of(rot):
+        return (d_head * math.log(original / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim_of(beta_fast)), 0)
+    hi = min(math.ceil(dim_of(beta_slow)), d_head - 1)
+    if lo == hi:
+        hi += 0.001
+    extra = 1.0 / theta ** (np.arange(0, d_head, 2, dtype=np.float64)
+                            / d_head)
+    ramp = np.clip((np.arange(d_head // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (extra / factor * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs=None, scale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, Dh) or (..., S, Dh); positions: (..., S).  Rotates
+    the two halves of the last axis against each other; ``freqs``
+    (Dh/2,) replaces the plain ``theta`` frequencies and ``scale``
+    multiplies cos and sin (YaRN)."""
     d_head = x.shape[-1]
-    freqs = rope_freqs(d_head, theta)                    # (Dh/2,)
+    if freqs is None:
+        freqs = rope_freqs(d_head, theta)                # (Dh/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, Dh/2)
     if x.ndim == ang.ndim + 1:                           # head axis present
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

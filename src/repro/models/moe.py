@@ -1,19 +1,28 @@
-"""Mixture-of-Experts with sort-based capacity dispatch (dropless up to the
-capacity factor) and expert parallelism over the "experts" logical axis.
+"""Mixture-of-Experts layer holding one chip's share of the experts.
 
-Design notes (DESIGN.md §7): MoE routing is itself *coarse-grained
-activation sparsity* — the router is a learned top-k over expert 'units',
-directly analogous to the paper's k-WTA over neurons.  Complementary
-sparsity composes inside each expert's FFN (fine-grained weight sparsity),
-giving the 'two sparsities' at two granularities.
+MoE routing is itself coarse-grained activation sparsity: the router is a
+learned top-k over expert units, as k-WTA is a top-k over neurons.
+Complementary sparsity composes inside each expert's FFN (packed weights,
+k-WTA on the hidden units), so the two sparsities act at two
+granularities.
 
-Dispatch is static-shaped and TPU-friendly:
-  1. top-k expert choice per token (router softmax),
-  2. stable argsort of the (T·k) assignments by expert id,
-  3. rank-within-expert via running offsets; tokens beyond capacity C drop,
-  4. scatter into an (E, C, d) buffer, batched expert FFN (one einsum per
-     projection, E sharded over the model axis = EP),
-  5. weighted combine back via the inverse gather.
+Under expert parallelism each chip holds a contiguous range of the routed
+experts (``cfg.held_expert_start``, ``cfg.n_held_experts``).  The router
+keeps its full width: every token is scored against all ``n_experts`` and
+takes its top ``experts_per_token`` of them.  This chip then computes the
+contributions of its own experts, for every (token, held expert) pair
+routed, and never drops one: each held expert runs on every token of the
+call and its output is weighted by the token's router weight for it,
+which is zero where the pair was not routed.  At a decode batch or a
+prefill chunk (tens of rows) that costs no more than a dispatch buffer
+with room for every token, and needs no sort, scatter or capacity.  The
+shared experts run on every chip.  What experts held elsewhere add is
+left out here; no code stands in for the absent chips or their exchange.
+
+Expert projections go through ``packed_linear_apply`` (vmapped over the
+held experts), so they take the same path rule as the dense FFN.  One
+route table per projection serves every expert: the gather of the shared
+input runs once, not once per expert.
 """
 
 from __future__ import annotations
@@ -26,23 +35,27 @@ import numpy as np
 from jax import lax
 
 from repro.core.api import SparsityConfig
-from repro.sharding.context import constrain
+from repro.core.layers import apply_kwta, packed_linear_apply
 from .common import normal_init
+from .ffn import ffn_apply, ffn_init
 
 
 def moe_init(key, d_model: int, d_ff: int, n_experts: int,
-             n_shared: int, act: str, cfg_sp: SparsityConfig):
-    """Experts hold stacked SwiGLU weights (E, d, ff)/(E, ff, d).
+             n_shared: int, act: str, cfg_sp: SparsityConfig,
+             n_held: int = 0):
+    """Router ``(d_model, n_experts)``, the ``n_held`` held experts'
+    stacked SwiGLU weights (``n_held`` 0: all ``n_experts``) and the
+    shared experts as one FFN of width ``n_shared * d_ff``.
 
     When cfg_sp.weight_sparse, expert weights are stored packed:
-    (E, G, P, N) with a single route table shared across experts (a codesign
-    choice — routes are arbitrary, sharing keeps the HLO small; per-expert
-    connectivity diversity is preserved by the weights themselves).
+    (E, G, P, N) with one route table per projection shared across
+    experts (per-expert connectivity diversity comes from the weights).
     """
+    n_held = n_held or n_experts
     ks = jax.random.split(key, 5)
     params, specs = {}, {}
     params["router"] = normal_init(ks[0], (d_model, n_experts), 0.02)
-    specs["router"] = (None, "experts")
+    specs["router"] = (None, None)
 
     def mk_expert(key, d_in, d_out, seed):
         if cfg_sp.weight_sparse and d_in % cfg_sp.n == 0 and d_out % cfg_sp.n == 0:
@@ -56,13 +69,13 @@ def moe_init(key, d_model: int, d_ff: int, n_experts: int,
                 CSLayout(d_in, cfg_sp.n * (g // r), cfg_sp.n,
                          cfg_sp.perm_kind), seed)
             scale = np.sqrt(cfg_sp.n / d_in)
-            w = jax.random.uniform(key, (n_experts, g, lay.partitions, cfg_sp.n),
+            w = jax.random.uniform(key, (n_held, g, lay.partitions, cfg_sp.n),
                                    jnp.float32, -scale, scale)
             return ({"packed": w, "route": jnp.asarray(route)},
                     {"packed": ("experts", "mlp", None, None),
                      "route": ("mlp", None, None)})
         scale = 1.0 / np.sqrt(d_in)
-        w = jax.random.uniform(key, (n_experts, d_in, d_out), jnp.float32,
+        w = jax.random.uniform(key, (n_held, d_in, d_out), jnp.float32,
                                -scale, scale)
         return {"w": w}, {"w": ("experts", None, "mlp" if d_out == d_ff else None)}
 
@@ -71,110 +84,77 @@ def moe_init(key, d_model: int, d_ff: int, n_experts: int,
         params["gate"], specs["gate"] = mk_expert(ks[2], d_model, d_ff, 32)
     params["down"], specs["down"] = mk_expert(ks[3], d_ff, d_model, 33)
     if n_shared:
-        from .ffn import ffn_init
         params["shared"], specs["shared"] = ffn_init(
             ks[4], d_model, n_shared * d_ff, cfg_sp, act)
     return params, specs
 
 
-def _expert_matmul(p, x, sp: SparsityConfig):
-    """Batched expert projection: x (..., E, C, d_in) -> (..., E, C,
-    d_out)."""
-    if "packed" in p:
-        from repro.core import functional as F
-        pk = p["packed"].astype(x.dtype)
-        fn = lambda xe, pe: F.cs_matmul(xe, pe, p["route"])  # noqa: E731
-        over_e = jax.vmap(fn, in_axes=(0, 0))
-        if x.ndim == 4:  # leading group axis
-            return jax.vmap(over_e, in_axes=(0, None))(x, pk)
-        return over_e(x, pk)
-    return jnp.einsum("...ecd,edf->...ecf", x, p["w"].astype(x.dtype))
+def _experts_matmul(p, x, sp: SparsityConfig, x_is_sparse: bool = False):
+    """Every held expert's projection.  x: (T, d_in), shared by all
+    experts, or (E, T, d_in), one input per expert.  Returns
+    (E, T, d_out)."""
+    if "packed" not in p:
+        eq = "td,edf->etf" if x.ndim == 2 else "etd,edf->etf"
+        return jnp.einsum(eq, x, p["w"].astype(x.dtype))
+
+    def one(packed, xe):
+        return packed_linear_apply({"packed": packed, "route": p["route"]},
+                                   xe, sp, x_is_sparse=x_is_sparse)
+
+    return jax.vmap(one, in_axes=(0, None if x.ndim == 2 else 0))(
+        p["packed"], x)
 
 
-def _dispatch_group(xg, top_p, top_e, e: int, k: int, cap: int):
-    """Sort-based dispatch for ONE token group.
-
-    xg: (Tg, d); top_p/top_e: (Tg, k). Returns (buf (E, C, d),
-    e_sorted, rank_c, keep, w_sorted, tok_sorted) for the combine."""
-    tg, d = xg.shape
-    e_flat = top_e.reshape(-1)                               # (Tg*k,)
-    order = jnp.argsort(e_flat, stable=True)
-    e_sorted = e_flat[order]
-    tok_sorted = order // k
-    counts = jnp.bincount(e_sorted, length=e)
-    starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
-                              jnp.cumsum(counts)[:-1]])
-    rank = jnp.arange(tg * k) - starts[e_sorted]
-    keep = rank < cap
-    rank_c = jnp.where(keep, rank, cap - 1).astype(jnp.int32)
-    buf = jnp.zeros((e, cap, d), xg.dtype)
-    src = jnp.where(keep[:, None], xg[tok_sorted], 0).astype(xg.dtype)
-    buf = buf.at[e_sorted, rank_c].add(src)                  # (E, C, d)
-    w_sorted = top_p.reshape(-1)[order]
-    return buf, e_sorted, rank_c, keep, w_sorted, tok_sorted
+def route(router, x, cfg):
+    """Top-k routing over all ``n_experts``.  x: (T, d).  Returns the
+    router probabilities (T, E) and the top-k weights and expert ids
+    (T, k).  Scores are taken in float32 at full precision, as
+    published: a rounding of the logits would change which experts a
+    near-tie picks."""
+    logits = jnp.matmul(x.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_e
 
 
-def _combine_group(out, e_sorted, rank_c, keep, w_sorted, tok_sorted,
-                   tg: int):
-    gathered = out[e_sorted, rank_c]                         # (Tg*k, d)
-    contrib = gathered * (w_sorted * keep)[:, None].astype(out.dtype)
-    return jnp.zeros((tg, out.shape[-1]), out.dtype).at[tok_sorted].add(
-        contrib)
-
-
-def moe_apply(params, x, cfg, cfg_sp: SparsityConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D). Returns (y, aux_loss).
-
-    Dispatch runs **per token group** (vmapped): the group axis preserves
-    the batch sharding, so the (groups, E, C, d) buffer shards over DP x EP
-    and the scatter/sort never crosses data shards.  A single global
-    dispatch (no group axis) has no batch dim on the buffer — GSPMD
-    replicates the scatter and the 1M-token qwen3 dispatch buffer exploded
-    to ~420 GB/device (measured; see EXPERIMENTS.md §Perf)."""
-    b, s, d = x.shape
+def moe_apply(params, x, cfg, cfg_sp: SparsityConfig
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (..., D).  Returns (y, aux_loss, held) where ``held`` (...,)
+    counts each token's routed pairs that went to an expert held here."""
+    lead, d = x.shape[:-1], x.shape[-1]
     e, k = cfg.n_experts, cfg.experts_per_token
-    t = b * s
-    # group count: one group per batch row keeps sharding natural
-    groups = b
-    tg = t // groups
-    xg = x.reshape(groups, tg, d)
-    logits = (xg @ params["router"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                  # (G, Tg, E)
-    top_p, top_e = lax.top_k(probs, k)                       # (G, Tg, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    lo, n_held = cfg.held_expert_start, cfg.n_held_experts
+    xt = x.reshape(-1, d)
+    t = xt.shape[0]
+    with jax.named_scope("moe.route"):
+        probs, top_p, top_e = route(params["router"], xt, cfg)
+        # Load-balancing auxiliary loss (Switch-style, over all experts).
+        me = probs.mean(axis=0)
+        ce = jnp.zeros((e,), jnp.float32).at[top_e.reshape(-1)].add(
+            1.0 / (t * k))
+        aux = e * jnp.sum(me * ce)
+        # (E_held, T) weight of each held expert for each token: its
+        # router weight where the pair was routed, else zero.
+        held_ids = lo + jnp.arange(n_held, dtype=top_e.dtype)
+        hit = top_e[None, :, :] == held_ids[:, None, None]   # (E, T, k)
+        gate_w = jnp.sum(jnp.where(hit, top_p[None], 0.0), axis=-1)
+        held = jnp.sum(hit, axis=(0, 2)).astype(jnp.int32)
 
-    # ---- load-balancing auxiliary loss (Switch-style, global) ----
-    me = probs.mean(axis=(0, 1))                             # (E,)
-    ce = jnp.zeros((e,), jnp.float32).at[top_e.reshape(-1)].add(
-        1.0 / (t * k))
-    aux = e * jnp.sum(me * ce)
-
-    # ---- per-group sort-based dispatch (vmapped) ----
-    cap = int(np.ceil(tg * k / e * cfg.capacity_factor))
-    buf, e_sorted, rank_c, keep, w_sorted, tok_sorted = jax.vmap(
-        lambda xg_, p_, e_: _dispatch_group(xg_, p_, e_, e, k, cap)
-    )(xg, top_p, top_e)
-    buf = constrain(buf, "batch", "experts", None, None)     # (G, E, C, d)
-
-    # ---- batched expert FFN (experts sharded over model = EP) ----
-    up = _expert_matmul(params["up"], buf, cfg_sp)
-    if "gate" in params:
-        h = jax.nn.silu(_expert_matmul(params["gate"], buf, cfg_sp)) * up
-    else:
-        h = jax.nn.gelu(up)
-    if cfg_sp.activation_sparse:
-        from repro.core.layers import apply_kwta
+    with jax.named_scope("moe.experts"):
+        up = _experts_matmul(params["up"], xt, cfg_sp)       # (E, T, ff)
+        if "gate" in params:
+            h = jax.nn.silu(_experts_matmul(params["gate"], xt, cfg_sp)) * up
+        else:
+            h = jax.nn.gelu(up)
         h = apply_kwta(h, cfg_sp)
-    out = _expert_matmul(params["down"], h, cfg_sp)          # (G, E, C, d)
-    out = constrain(out, "batch", "experts", None, None)
-
-    # ---- combine (vmapped inverse gather) ----
-    y = jax.vmap(lambda o, es, rc, kp, ws, ts: _combine_group(
-        o, es, rc, kp, ws, ts, tg))(out, e_sorted, rank_c, keep, w_sorted,
-                                    tok_sorted)
-    y = y.reshape(b, s, d)
+        out = _experts_matmul(params["down"], h, cfg_sp,
+                              x_is_sparse=cfg_sp.activation_sparse)
+        y = jnp.einsum("et,etd->td", gate_w.astype(out.dtype), out)
 
     if "shared" in params:
-        from .ffn import ffn_apply
-        y = y + ffn_apply(params["shared"], x, cfg_sp, "silu")
-    return y, aux
+        with jax.named_scope("moe.shared"):
+            y = y + ffn_apply(params["shared"], xt, cfg_sp, "silu")
+    return y.reshape(*lead, d), aux, held.reshape(lead)

@@ -44,7 +44,10 @@ from .moe import moe_apply, moe_init
 # Block init/apply/decode dispatch
 # ---------------------------------------------------------------------------
 
-def _block_init(kind: str, key, cfg):
+def _block_init(kind: str, key, cfg, dense_d_ff: int = 0):
+    """One block's params; ``dense_d_ff`` gives an attention block a dense
+    FFN of that width in place of the config's FFN or expert layer (the
+    leading dense layers)."""
     ks = jax.random.split(key, 4)
     if kind in ("attn", "shared_attn"):
         p, s = {}, {}
@@ -54,10 +57,14 @@ def _block_init(kind: str, key, cfg):
         else:
             p["mixer"], s["mixer"] = A.gqa_init(ks[0], cfg)
         p["norm2"], s["norm2"] = rmsnorm_init(cfg.d_model)
-        if cfg.is_moe and kind == "attn":
+        if dense_d_ff:
+            p["ffn"], s["ffn"] = ffn_init(ks[1], cfg.d_model, dense_d_ff,
+                                          cfg.ffn_sparsity, cfg.act)
+        elif cfg.is_moe and kind == "attn":
             p["moe"], s["moe"] = moe_init(ks[1], cfg.d_model, cfg.d_ff,
                                           cfg.n_experts, cfg.n_shared_experts,
-                                          cfg.act, cfg.ffn_sparsity)
+                                          cfg.act, cfg.ffn_sparsity,
+                                          n_held=cfg.n_held_experts)
         elif cfg.d_ff > 0:
             p["ffn"], s["ffn"] = ffn_init(ks[1], cfg.d_model, cfg.d_ff,
                                           cfg.ffn_sparsity, cfg.act)
@@ -92,7 +99,7 @@ def _block_apply(kind: str, params, x, cfg, positions):
         x = x + h
         h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
         if "moe" in params:
-            h, aux = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
+            h, aux, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
             x = x + h
         elif "ffn" in params:
             x = x + ffn_apply(params["ffn"], h, cfg.ffn_sparsity, cfg.act)
@@ -118,9 +125,12 @@ def _block_cache_init(kind: str, cfg, batch: int, max_seq: int, dtype):
 
 
 def _block_decode(kind: str, params, x, cfg, cache, pos, pages=None):
-    """One-token step. Returns (x, new_cache).  ``pages`` (the paged KV
-    layout's per-slot page table) is attention-only: SSM blocks keep O(1)
-    recurrence state and have no per-position rows to page."""
+    """One-token step. Returns (x, new_cache, held): ``held`` is an
+    expert block's (B, 1) count of each row's routed pairs that went to
+    an expert held here, and ``()`` for every other block (no leaf).
+    ``pages`` (the paged KV layout's per-slot page table) is
+    attention-only: SSM blocks keep O(1) recurrence state and have no
+    per-position rows to page."""
     if kind in ("attn", "shared_attn"):
         h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps)
         dec = A.mla_decode if cfg.use_mla else A.gqa_decode
@@ -128,12 +138,13 @@ def _block_decode(kind: str, params, x, cfg, cache, pos, pages=None):
                            pages=pages)
         x = x + h
         h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
+        held = ()
         if "moe" in params:
-            h, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
+            h, _, held = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
             x = x + h
         elif "ffn" in params:
             x = x + ffn_apply(params["ffn"], h, cfg.ffn_sparsity, cfg.act)
-        return x, new_cache
+        return x, new_cache, held
     if pages is not None:
         raise NotImplementedError(
             f"paged KV layout not implemented for block kind {kind!r} "
@@ -142,17 +153,60 @@ def _block_decode(kind: str, params, x, cfg, cache, pos, pages=None):
     dec = {"mamba2": S.mamba2_decode, "mlstm": S.mlstm_decode,
            "slstm": S.slstm_decode}[kind]
     h, new_cache = dec(params["mixer"], h, cfg, cache, pos)
-    return x + h, new_cache
+    return x + h, new_cache, ()
 
 
 # ---------------------------------------------------------------------------
 # Model init
 # ---------------------------------------------------------------------------
 
+#: Salt of the leading dense layers' key (the other params' keys are
+#: unchanged by their presence).
+LEAD_KEY = 0x1EAD
+
+
+def _stack(trees):
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+
+
+def _repeat(tree, n: int):
+    return jax.tree.map(lambda x: jnp.broadcast_to(x, (n, *x.shape)), tree)
+
+
+def _stack_specs(spec):
+    return jax.tree.map(lambda sp: (None,) + tuple(sp), spec,
+                        is_leaf=_is_spec)
+
+
+def _lead_init(key, cfg):
+    """The leading dense layers (``cfg.n_dense_layers`` attention blocks
+    with a dense FFN of width ``cfg.dense_d_ff``), stacked on a leading
+    layer axis like the scanned units but applied outside the scan."""
+    ps = [_block_init("attn", k, cfg, dense_d_ff=cfg.dense_d_ff)
+          for k in jax.random.split(key, cfg.n_dense_layers)]
+    return _stack([p for p, _ in ps]), _stack_specs(ps[0][1])
+
+
+def _lead_layers(tree):
+    """The per-layer slices of a stacked ``lead`` tree (params or
+    cache); empty when the model has no leading dense layer."""
+    if tree is None:
+        return []
+    n = jax.tree.leaves(tree)[0].shape[0]
+    return [jax.tree.map(lambda a, i=i: a[i], tree) for i in range(n)]
+
+
+def _split_cache(cache):
+    """(the leading layers' cache or None, the scanned units' cache)."""
+    units = {k: v for k, v in cache.items() if k != "lead"}
+    return cache.get("lead"), units
+
+
 def init_model(key, cfg) -> Tuple[Dict, Dict]:
     """Returns (params, specs).  params["units"] leaves have leading dim
-    n_units (scanned); params["shared"] (if any) is the zamba2 shared
-    block."""
+    n_units (scanned); params["lead"] (if any) holds the leading dense
+    layers, stacked, applied before the scan; params["shared"] (if any)
+    is the zamba2 shared block."""
     keys = jax.random.split(key, cfg.n_units + 3)
     params: Dict[str, Any] = {}
     specs: Dict[str, Any] = {}
@@ -173,14 +227,14 @@ def init_model(key, cfg) -> Tuple[Dict, Dict]:
             p[f"b{i}"], s[f"b{i}"] = _block_init(kind, ks[i], cfg)
         return p, s
 
+    if cfg.n_dense_layers:
+        params["lead"], specs["lead"] = _lead_init(
+            jax.random.fold_in(key, LEAD_KEY), cfg)
+
     unit_ps = [unit_init(keys[2 + u]) for u in range(cfg.n_units)]
-    params["units"] = jax.tree.map(lambda *xs: jnp.stack(xs), *
-                                   [p for p, _ in unit_ps])
+    params["units"] = _stack([p for p, _ in unit_ps])
     # specs: identical across units; prepend the (unsharded) layer axis
-    unit_spec = unit_ps[0][1]
-    specs["units"] = jax.tree.map(
-        lambda sp: (None,) + tuple(sp), unit_spec,
-        is_leaf=_is_spec)
+    specs["units"] = _stack_specs(unit_ps[0][1])
 
     params["final_norm"], specs["final_norm"] = rmsnorm_init(cfg.d_model)
     if not cfg.tie_embeddings:
@@ -226,6 +280,9 @@ def forward(params, batch, cfg) -> Tuple[jax.Array, jax.Array]:
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     shared = params.get("shared")
+    for i, p in enumerate(_lead_layers(params.get("lead"))):
+        with jax.named_scope(f"lead{i}"):
+            x, _ = _block_apply("attn", p, x, cfg, positions)
 
     def unit_fn(carry, unit_params):
         x, aux = carry
@@ -274,11 +331,11 @@ def init_cache(cfg, batch: int, max_seq: int):
     for i, kind in enumerate(cfg.block_pattern):
         c, sp = _block_cache_init(kind, cfg, batch, max_seq, ct)
         unit_cache[f"b{i}"], unit_specs[f"b{i}"] = c, sp
-    cache = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (cfg.n_units, *x.shape)), unit_cache)
-    specs = jax.tree.map(
-        lambda sp: (None,) + tuple(sp), unit_specs,
-        is_leaf=_is_spec)
+    cache, specs = _repeat(unit_cache, cfg.n_units), _stack_specs(unit_specs)
+    if cfg.n_dense_layers:
+        c, sp = _block_cache_init("attn", cfg, batch, max_seq, ct)
+        cache["lead"] = _repeat(c, cfg.n_dense_layers)
+        specs["lead"] = _stack_specs(sp)
     return cache, specs
 
 
@@ -304,11 +361,15 @@ def init_paged_cache(cfg, n_pages: int, page_size: int):
         unit_cache[f"b{i}"] = c
         unit_specs[f"b{i}"] = jax.tree.map(
             lambda s: (None, None) + tuple(s)[2:], sp, is_leaf=_is_spec)
-    cache = jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (cfg.n_units, *x.shape)), unit_cache)
-    specs = jax.tree.map(
-        lambda sp: (None,) + tuple(sp), unit_specs,
-        is_leaf=_is_spec)
+    cache, specs = _repeat(unit_cache, cfg.n_units), _stack_specs(unit_specs)
+    if cfg.n_dense_layers:
+        # the leading layers' own pool leaves, stacked over those layers:
+        # the same page ids address them, so copy-on-write and prefix
+        # sharing cover them with the scanned leaves
+        c, sp = _block_cache_init("attn", cfg, n_pages, page_size, ct)
+        cache["lead"] = _repeat(c, cfg.n_dense_layers)
+        specs["lead"] = _stack_specs(jax.tree.map(
+            lambda s: (None, None) + tuple(s)[2:], sp, is_leaf=_is_spec))
     return cache, specs
 
 
@@ -318,7 +379,8 @@ def copy_cache_page(cache, src, dst):
     copy-on-write break (the allocator already swapped ``dst`` into the
     writer's chain; this materialises the shared rows there before the
     writer's next scatter lands).  src/dst: scalar int32 page ids; leaf
-    layout ``(n_units, n_pages, page_size, ...)``."""
+    layout ``(n_units, n_pages, page_size, ...)``, or ``(n_dense_layers,
+    ...)`` for the leading layers' leaves."""
     return jax.tree.map(lambda leaf: leaf.at[:, dst].set(leaf[:, src]),
                         cache)
 
@@ -343,7 +405,7 @@ def _block_prefill(kind: str, params, x, cfg, positions, max_seq: int):
     x = x + h
     h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
     if "moe" in params:
-        h, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
+        h, _, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
         x = x + h
     elif "ffn" in params:
         x = x + ffn_apply(params["ffn"], h, cfg.ffn_sparsity, cfg.act)
@@ -369,6 +431,11 @@ def prefill(params, batch, cfg, max_seq: int):
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     shared = params.get("shared")
+    lead_caches = []
+    for i, p in enumerate(_lead_layers(params.get("lead"))):
+        with jax.named_scope(f"lead{i}"):
+            x, c = _block_prefill("attn", p, x, cfg, positions, max_seq)
+        lead_caches.append(c)
 
     def unit_fn(x, unit_params):
         caches = {}
@@ -383,13 +450,16 @@ def prefill(params, batch, cfg, max_seq: int):
 
     x, (cache, sparsity_aux) = lax.scan(unit_fn, x, params["units"])
     obs_sparsity.emit_stacked(sparsity_aux)
+    if lead_caches:
+        cache["lead"] = _stack(lead_caches)
     x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     table = (params["embed"] if cfg.tie_embeddings else params["head"])["table"]
     logits = x @ table.astype(ct).T
     return constrain(logits, "batch", "seq", "vocab"), cache
 
 
-def serve_step(params, cache, batch, pos, cfg, pages=None):
+def serve_step(params, cache, batch, pos, cfg, pages=None,
+               moe_counts: bool = False):
     """Decode one token given caches of past state.
 
     batch: {"tokens": (B, 1)} (or {"embeds": (B, 1, D)}).
@@ -399,7 +469,9 @@ def serve_step(params, cache, batch, pos, cfg, pages=None):
     leaves are then the :func:`init_paged_cache` pools and every
     attention read/write goes through the page indirection (same math,
     same mask; token-exact vs the contiguous layout).
-    Returns (logits (B, vocab), new_cache).
+    Returns (logits (B, vocab), new_cache); with ``moe_counts`` (a
+    config with experts) also each row's routed pairs, over every expert
+    layer, that went to an expert held here, (B,) int32.
 
     Sparse-sparse decode runs the fused pipeline per layer: the FFN's
     k-WTA Select hands its (vals, idx) support straight to the down
@@ -414,30 +486,47 @@ def serve_step(params, cache, batch, pos, cfg, pages=None):
         x = jnp.take(params["embed"]["table"].astype(ct), batch["tokens"],
                      axis=0)
     shared = params.get("shared")
+    lead_cache, cache = _split_cache(cache)
+    lead_new = []
+    for i, (p, c) in enumerate(zip(_lead_layers(params.get("lead")),
+                                   _lead_layers(lead_cache))):
+        with jax.named_scope(f"lead{i}"):
+            x, c, _ = _block_decode("attn", p, x, cfg, c, pos, pages)
+        lead_new.append(c)
 
     def unit_fn(x, scanned):
         unit_params, unit_cache = scanned
-        new_cache = {}
+        new_cache, held = {}, []
         for i, kind in enumerate(cfg.block_pattern):
             p = shared if kind == "shared_attn" else unit_params[f"b{i}"]
             with jax.named_scope(f"b{i}_{kind}"), \
                     obs_sparsity.observe_site(f"b{i}"):
-                x, new_cache[f"b{i}"] = _block_decode(
+                x, new_cache[f"b{i}"], h = _block_decode(
                     kind, p, x, cfg, unit_cache[f"b{i}"], pos, pages)
+            if moe_counts and not isinstance(h, tuple):
+                held.append(h[:, 0])
         # Realized-sparsity capture handoff: when the serving engine's
         # probed step is tracing, the winner sets observed inside this
         # body leave the scan as stacked (n_units, ...) outputs.  With no
         # active capture this is the empty tuple — zero extra leaves, the
-        # staged jaxpr is unchanged (asserted by tests/test_obs.py).
-        return x, (new_cache, obs_sparsity.drain_pending())
+        # staged jaxpr is unchanged (asserted by tests/test_obs.py).  The
+        # held counts are an output only when asked for.
+        held = (sum(held),) if held else ()
+        return x, (new_cache, obs_sparsity.drain_pending(), held)
 
-    x, (new_cache, sparsity_aux) = lax.scan(unit_fn, x,
-                                            (params["units"], cache))
+    x, (new_cache, sparsity_aux, held) = lax.scan(
+        unit_fn, x, (params["units"], cache))
     obs_sparsity.emit_stacked(sparsity_aux)
+    if lead_new:
+        new_cache["lead"] = _stack(lead_new)
     x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     table = (params["embed"] if cfg.tie_embeddings else params["head"])["table"]
-    logits = (x @ table.astype(ct).T)[:, 0]
-    return constrain(logits, "batch", "vocab"), new_cache
+    logits = constrain((x @ table.astype(ct).T)[:, 0], "batch", "vocab")
+    if moe_counts:
+        rows = jnp.sum(held[0], axis=0) if held else jnp.zeros(
+            x.shape[:1], jnp.int32)
+        return logits, new_cache, rows
+    return logits, new_cache
 
 
 def _block_chunk_prefill(kind: str, params, x, cfg, cache, pages,
@@ -454,7 +543,7 @@ def _block_chunk_prefill(kind: str, params, x, cfg, cache, pages,
     x = x + h
     h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
     if "moe" in params:
-        h, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
+        h, _, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
         x = x + h
     elif "ffn" in params:
         x = x + ffn_apply(params["ffn"], h, cfg.ffn_sparsity, cfg.act)
@@ -482,6 +571,14 @@ def prefill_chunk(params, cache, batch, pos_start, chunk_len, cfg, pages):
         x = jnp.take(params["embed"]["table"].astype(ct), batch["tokens"],
                      axis=0)
     shared = params.get("shared")
+    lead_cache, cache = _split_cache(cache)
+    lead_new = []
+    for i, (p, c) in enumerate(zip(_lead_layers(params.get("lead")),
+                                   _lead_layers(lead_cache))):
+        with jax.named_scope(f"lead{i}"):
+            x, c = _block_chunk_prefill("attn", p, x, cfg, c, pages,
+                                        pos_start, chunk_len)
+        lead_new.append(c)
 
     def unit_fn(x, scanned):
         unit_params, unit_cache = scanned
@@ -499,6 +596,8 @@ def prefill_chunk(params, cache, batch, pos_start, chunk_len, cfg, pages):
     x, (new_cache, sparsity_aux) = lax.scan(unit_fn, x,
                                             (params["units"], cache))
     obs_sparsity.emit_stacked(sparsity_aux)
+    if lead_new:
+        new_cache["lead"] = _stack(lead_new)
     x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     table = (params["embed"] if cfg.tie_embeddings else params["head"])["table"]
     logits = x @ table.astype(ct).T

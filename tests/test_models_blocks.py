@@ -81,35 +81,36 @@ def test_ssd_step_continues_scan():
 
 
 def test_moe_conserves_tokens_and_balances():
-    """Every kept token's output is the capacity-weighted expert mix; with
-    generous capacity nothing drops and the combine is exact for a linear
-    'expert'."""
+    """Every token's output is its router-weighted expert mix, with no
+    capacity to drop any; every routed pair lands on a held expert when
+    the layer holds them all."""
     from repro.models.moe import moe_apply
     cfg = get_config("qwen3_moe_235b_a22b").reduced(
-        n_experts=4, experts_per_token=2, capacity_factor=4.0)
+        n_experts=4, experts_per_token=2)
     d, ff = cfg.d_model, cfg.d_ff
     key = jax.random.PRNGKey(0)
     from repro.models.moe import moe_init
     params, _ = moe_init(key, d, ff, 4, 0, "silu", cfg.ffn_sparsity)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, d), jnp.float32)
-    y, aux = moe_apply(params, x, cfg, cfg.ffn_sparsity)
+    y, aux, held = moe_apply(params, x, cfg, cfg.ffn_sparsity)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
     assert 0.5 < float(aux) < 10.0  # aux ~ 1 for near-uniform routing
+    np.testing.assert_array_equal(np.asarray(held), 2)
 
 
 def test_moe_group_vs_global_equivalence():
-    """Grouped dispatch must compute the same function as a single-group
-    dispatch when capacity is non-binding."""
+    """The layer is a function of each token alone: a (4, 8) batch gives
+    what the same 32 tokens give as one row."""
     from repro.models.moe import moe_apply, moe_init
     cfg = get_config("qwen3_moe_235b_a22b").reduced(
-        n_experts=4, experts_per_token=2, capacity_factor=8.0)
+        n_experts=4, experts_per_token=2)
     d, ff = cfg.d_model, cfg.d_ff
     params, _ = moe_init(jax.random.PRNGKey(0), d, ff, 4, 0, "silu",
                          cfg.ffn_sparsity)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, d), jnp.float32)
-    y4, _ = moe_apply(params, x, cfg, cfg.ffn_sparsity)     # 4 groups
-    y1, _ = moe_apply(params, x.reshape(1, 32, d), cfg, cfg.ffn_sparsity)
+    y4, _, _ = moe_apply(params, x, cfg, cfg.ffn_sparsity)
+    y1, _, _ = moe_apply(params, x.reshape(1, 32, d), cfg, cfg.ffn_sparsity)
     np.testing.assert_allclose(np.asarray(y4).reshape(1, 32, d),
                                np.asarray(y1), atol=1e-4)
 
