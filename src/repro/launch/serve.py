@@ -92,7 +92,9 @@ from repro.configs import get_config
 from repro.core.api import observe_dispatch
 from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_mesh
+from repro.launch.served_weights import served_params
 from repro.models import transformer as T
+from repro.models.common import dtype_of
 from repro.obs import DispatchStats, SparsityStats, Telemetry
 from repro.obs import sparsity as obs_sparsity
 from repro.runtime.kvcache import (NULL_PAGE, BlockAllocator, PagedKV,
@@ -119,7 +121,12 @@ class Engine:
     only), 'force' (everywhere, interpret fallback off-TPU) or 'off' (pure
     jnp).  With the sparse-sparse config this is what routes the decode
     batch through the batched ``topk_gather`` kernel — one launch per
-    sparse layer per decode step."""
+    sparse layer per decode step.
+
+    ``params`` stays the master tree; every compiled program is given
+    ``served_params``, in which each weight the programs read only
+    through a cast to the compute dtype has been cast once, here
+    (:mod:`repro.launch.served_weights`)."""
 
     def __init__(self, cfg, mesh, max_seq: int, n_slots: int = 4,
                  params=None, use_pallas: Optional[str] = None,
@@ -151,7 +158,10 @@ class Engine:
                 params, specs = T.init_model(jax.random.PRNGKey(0), cfg)
                 p_shard = param_sharding(specs, params, self.rules)
                 params = jax.device_put(params, p_shard)
+            #: the master tree, as given (float32 where the config says)
             self.params = params
+        self.telemetry = telemetry if telemetry is not None \
+            else Telemetry.off()
         # Named functions, not lambdas: a device trace names each
         # program after its function (``jit_decode_step_paged``).
         def decode_step(p, c, b, pos):
@@ -214,8 +224,6 @@ class Engine:
             self._step_paged_probed = jax.jit(_probed_step_paged,
                                               donate_argnums=(1,))
         # -- telemetry ------------------------------------------------------
-        self.telemetry = telemetry if telemetry is not None \
-            else Telemetry.off()
         self._sparsity = SparsityStats(self.telemetry.registry)
         self._dispatch = DispatchStats()
         self._last_sched: Optional[Scheduler] = None
@@ -234,6 +242,35 @@ class Engine:
             return logits, new_cache, cap.take_arrays()
 
         self._step_probed = jax.jit(_probed_step, donate_argnums=(1,))
+        # -- the served weights ---------------------------------------------
+        # Each weight the programs read only through a cast to the compute
+        # dtype is cast here, once, and every program is given this tree
+        # (``served_weights`` says how the leaves are chosen).
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        step_in = ({"tokens": i32(n_slots, 1)}, i32(n_slots))
+        if kv_layout == "paged":
+            geo = self.kv_geo
+            cache = jax.eval_shape(lambda: T.init_paged_cache(
+                cfg, geo.n_pages, geo.page_size)[0])
+            n_pg = geo.empty_tables(1).shape[1]
+            staged = [(decode_step_paged, (cache, *step_in,
+                                           i32(n_slots, n_pg))),
+                      (prefill_chunk, (cache, i32(1, self.prefill_chunk),
+                                       i32(1, n_pg), i32(), i32()))]
+        else:
+            cache = jax.eval_shape(
+                lambda: T.init_cache(cfg, n_slots, max_seq)[0])
+            staged = [(decode_step, (cache, *step_in))]
+            if T.supports_fused_prefill(cfg):
+                staged.append((prefill, (i32(1, _bucket(1, max_seq)),)))
+        with use_rules(self.rules):
+            self.served_params, cast, master = served_params(
+                params, (jax.make_jaxpr(f)(params, *args)
+                         for f, args in staged),
+                dtype_of(cfg.compute_dtype))
+        reg = self.telemetry.registry
+        reg.gauge("engine.weights.cast_once_bytes", "bytes").set(cast)
+        reg.gauge("engine.weights.kept_bytes", "bytes").set(master)
 
     # -- compiled pieces ----------------------------------------------------
     @staticmethod
@@ -271,11 +308,11 @@ class Engine:
             tokens = {"tokens": jnp.zeros((self.n_slots, 1), jnp.int32)}
             pos = jnp.zeros((self.n_slots,), jnp.int32)
             if self.kv_geo is None:
-                return self._step.lower(self.params,
+                return self._step.lower(self.served_params,
                                         self.new_cache(self.n_slots),
                                         tokens, pos)
             tables = jnp.asarray(self.kv_geo.empty_tables(self.n_slots))
-            return self._step_paged.lower(self.params,
+            return self._step_paged.lower(self.served_params,
                                           self.new_paged_cache(), tokens,
                                           pos, tables)
 
@@ -295,7 +332,7 @@ class Engine:
         bucket = _bucket(p_len, self.max_seq)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :p_len] = np.asarray(prompt, np.int32)
-        logits, frag = self._prefill_jit(self.params, jnp.asarray(toks))
+        logits, frag = self._prefill_jit(self.served_params, jnp.asarray(toks))
         self.prefill_calls += 1
         self.telemetry.registry.counter("serve.prefill_calls").inc()
         return np.asarray(logits[0, p_len - 1]), frag
@@ -395,9 +432,9 @@ class Engine:
                                    jnp.asarray(pos))
                     if probed:
                         logits, cache, sp_aux = self._step_probed(
-                            self.params, cache, *step_in)
+                            self.served_params, cache, *step_in)
                     else:
-                        logits, cache = self._step(self.params, cache,
+                        logits, cache = self._step(self.served_params, cache,
                                                    *step_in)
                     with tracer.span("decode.fetch"):
                         logits = np.asarray(logits)
@@ -631,7 +668,7 @@ class Engine:
                                 jnp.int32(start), jnp.int32(ln))
                 # the jit call sits directly in prefill.chunk, with no
                 # child span open (see the obs README on device labels)
-                logits, cache = self._chunk_jit(self.params, cache,
+                logits, cache = self._chunk_jit(self.served_params, cache,
                                                 *chunk_in)
             c_chunks.inc()
             n_chunks += 1
@@ -715,11 +752,11 @@ class Engine:
                         # no child span open
                         if probed:
                             logits, cache, *held, sp_aux = \
-                                self._step_paged_probed(self.params, cache,
-                                                        *step_in)
+                                self._step_paged_probed(self.served_params,
+                                                        cache, *step_in)
                         else:
                             logits, cache, *held = self._step_paged(
-                                self.params, cache, *step_in)
+                                self.served_params, cache, *step_in)
                         with tracer.span("decode.fetch"):
                             logits = np.asarray(logits)
                             if held:
@@ -829,13 +866,13 @@ class Engine:
             logits = None
             for pos in range(p_len):
                 batch = {"tokens": jnp.asarray(prompts[:, pos:pos + 1])}
-                logits, cache = self._step(self.params, cache, batch,
+                logits, cache = self._step(self.served_params, cache, batch,
                                            jnp.int32(pos))
             out = []
             cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             for i in range(gen_len):
                 out.append(np.asarray(cur))
-                logits, cache = self._step(self.params, cache,
+                logits, cache = self._step(self.served_params, cache,
                                            {"tokens": cur},
                                            jnp.int32(p_len + i))
                 cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
