@@ -5,17 +5,15 @@ Prints ``name,us_per_call,derived`` CSV rows. Modules:
   bench_sparse_matmul — Figure 6 (structured-sparsity matmul paths)
   bench_resources     — Figures 15-18 (conv-block resource scaling)
   bench_kwta          — Figures 19-20 (k-WTA cost scaling)
-  bench_serve         — serving: continuous batching vs static, TTFT
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--only gsc,...]
-                                               [--json BENCH_serve.json]
+                                               [--json OUT]
 
 ``--json OUT`` additionally writes every collected row to a JSON file
 (``{"schema_version", "rows": [{"name", "us_per_call", ...derived}],
-"benches": [...]}``) — the machine-readable artifact future PRs gate perf
-on (CI uploads ``BENCH_serve.json`` from ``--only serve``).  Schema v2
-adds TTFT/ITL percentile and realized-sparsity columns to the serve
-telemetry row (see repro.obs.export).
+"benches": [...]}``).  These are CPU (and Pallas interpret-mode) timings
+at toy widths; the system's speed is measured on the chip
+(``benchmarks/chip/``).
 """
 
 from __future__ import annotations
@@ -48,18 +46,16 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: gsc,sparse_matmul,"
-                         "resources,kwta,serve")
+                         "resources,kwta")
     ap.add_argument("--json", default=None, metavar="OUT",
-                    help="also write collected rows to OUT as JSON "
-                         "(e.g. BENCH_serve.json for the CI artifact)")
+                    help="also write collected rows to OUT as JSON")
     args = ap.parse_args()
     from repro.launch.compile_cache import setup_compile_cache
     setup_compile_cache()
     from benchmarks import bench_gsc, bench_kwta, bench_resources, \
-        bench_serve, bench_sparse_matmul
+        bench_sparse_matmul
     mods = {"gsc": bench_gsc, "sparse_matmul": bench_sparse_matmul,
-            "resources": bench_resources, "kwta": bench_kwta,
-            "serve": bench_serve}
+            "resources": bench_resources, "kwta": bench_kwta}
     sel = (args.only.split(",") if args.only else list(mods))
     report = _Collector()
     print("name,us_per_call,derived")

@@ -136,8 +136,7 @@ def phase_kernel(cfg) -> None:
 def serve(cfg, mesh, reqs, events):
     """Serve ``reqs`` greedily on ``mesh``; returns (engine, outputs,
     stats, compiled decode step text)."""
-    engine = Engine(cfg, mesh, max_seq=MAX_SEQ, n_slots=N_SLOTS,
-                    kv_layout="paged", kv_policy="grow")
+    engine = Engine(cfg, mesh, max_seq=MAX_SEQ, n_slots=N_SLOTS)
     with observe_dispatch(events.append):
         t0 = time.perf_counter()
         out, stats = engine.serve(reqs)

@@ -1,5 +1,5 @@
 """Serve a (reduced) assigned-architecture LM with the continuous-batching
-engine: fused one-call prefill, slot-based KV cache, mid-flight admission,
+engine: chunked prefill into a paged KV cache, mid-flight admission,
 greedy or temperature/top-k sampling — the decode path the sparse-sparse
 topk dispatch targets.
 
@@ -57,7 +57,8 @@ if __name__ == "__main__":
     print(f"arch={cfg.name} served {len(out)} requests in "
           f"{stats['wall_s']:.2f}s: {stats['tok_s']:.1f} tok/s, "
           f"{stats['decode_steps']} decode steps, "
-          f"{stats['prefill_calls']} prefill calls (1 per prompt)")
+          f"{stats['prefill_calls']} prefill calls (1 per prompt), "
+          f"{stats['prefill_chunks']} prefill chunks")
     for uid in sorted(out)[:2]:
         print(f"  req {uid} ({len(out[uid])} toks, "
               f"ttft {stats['ttft_s'][uid]*1e3:.0f}ms):", out[uid][:12])

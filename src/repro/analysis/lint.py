@@ -5,11 +5,11 @@ Library API
 * :func:`lint_fn` — trace any callable with :func:`jax.make_jaxpr` and
   run the jaxpr rule pack over it.
 * :func:`lint_config` — lint the named architecture's real entrypoints
-  (decode step, paged decode step, fused prefill, the kwta→packed-
-  projection kernel pipeline, forward training loss) abstractly: params
-  and caches are
-  :func:`jax.eval_shape` pytrees, so even the full-scale configs lint on
-  a CPU without allocating a single weight.  The decode step is
+  (decode step, paged decode step, the paged prefill chunk, the
+  kwta→packed-projection kernel pipeline, forward training loss)
+  abstractly: params and caches are :func:`jax.eval_shape` pytrees, so
+  even the full-scale configs lint on a CPU without allocating a single
+  weight.  The decode step is
   additionally AOT-compiled and its HLO text checked (host transfers,
   unexpected collectives).
 * :func:`expected_selects` — the Select-count model: mirrors the exact
@@ -184,10 +184,11 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
 
 
-def _decode_batch(cfg, slots: int):
+def _step_batch(cfg, slots: int, rows: int):
+    """The inputs of a serving step: ``rows`` new positions per slot."""
     if cfg.frontend == "embed":
-        return {"embeds": _sds((slots, 1, cfg.d_model), jnp.float32)}
-    return {"tokens": _sds((slots, 1), jnp.int32)}
+        return {"embeds": _sds((slots, rows, cfg.d_model), jnp.float32)}
+    return {"tokens": _sds((slots, rows), jnp.int32)}
 
 
 def _seq_batch(cfg, batch: int, seq: int, labels: bool):
@@ -244,7 +245,7 @@ def lint_config(arch, entries: Sequence[str] = ENTRIES,
 
     if "decode" in entries:
         cache = jax.eval_shape(lambda: T.init_cache(cfg, slots, max_seq)[0])
-        batch = _decode_batch(cfg, slots)
+        batch = _step_batch(cfg, slots, 1)
         pos = _sds((slots,), jnp.int32)
         fn = lambda p, c, b, q: T.serve_step(p, c, b, q, cfg)
         exp = expected_selects(cfg, n_tokens=slots)
@@ -257,37 +258,45 @@ def lint_config(arch, entries: Sequence[str] = ENTRIES,
                 .compile().as_text()
             report.extend(lint_hlo(hlo, entry="decode", waivers=waivers))
 
-    if "decode_paged" in entries and all(
-            k in ("attn", "shared_attn") for k in cfg.block_pattern):
+    paged = all(k in ("attn", "shared_attn") for k in cfg.block_pattern)
+    if paged and ("decode_paged" in entries or "prefill" in entries):
+        from repro.runtime.kvcache import PagedKV
+        geo = PagedKV.build(max_seq, slots, page_size=16)
+        paged_cache = jax.eval_shape(lambda: T.init_paged_cache(
+            cfg, geo.n_pages, geo.page_size)[0])
+
+    if "decode_paged" in entries and paged:
         # Same decode step through the paged KV pools: the gather/scatter
         # indirection must not stage extra Selects, promote dtypes, or
         # (HLO) introduce host transfers — the page tables stay on device.
-        from repro.runtime.kvcache import PagedKV
-        geo = PagedKV.build(max_seq, slots, page_size=16)
-        cache = jax.eval_shape(lambda: T.init_paged_cache(
-            cfg, geo.n_pages, geo.page_size)[0])
-        batch = _decode_batch(cfg, slots)
+        batch = _step_batch(cfg, slots, 1)
         pos = _sds((slots,), jnp.int32)
         pages = _sds((slots, geo.blocks_per_slot), jnp.int32)
         fn = lambda p, c, b, q, pg: T.serve_step(p, c, b, q, cfg, pages=pg)
         exp = expected_selects(cfg, n_tokens=slots)
         report.extend(lint_fn(
-            fn, params, cache, batch, pos, pages, entry="decode_paged",
+            fn, params, paged_cache, batch, pos, pages, entry="decode_paged",
             expected=exp,
             check_dense_fallback=_wants_dense_fallback_rule(cfg, slots),
             backend=backend, waivers=waivers))
         if check_hlo:
-            hlo = jax.jit(fn).lower(params, cache, batch, pos, pages)\
+            hlo = jax.jit(fn).lower(params, paged_cache, batch, pos, pages)\
                 .compile().as_text()
             report.extend(lint_hlo(hlo, entry="decode_paged",
                                    waivers=waivers))
 
-    if "prefill" in entries and T.supports_fused_prefill(cfg):
-        batch = _seq_batch(cfg, 1, seq, labels=False)
-        fn = lambda p, b: T.prefill(p, b, cfg, max_seq)
+    if "prefill" in entries and paged:
+        # The chunk program the engine serves prompts with: one chunk of
+        # ``seq`` rows of one slot, scattered into its page chain.
+        batch = _step_batch(cfg, 1, seq)
+        pages = _sds((1, geo.blocks_per_slot), jnp.int32)
+        start, ln = _sds((), jnp.int32), _sds((), jnp.int32)
+        fn = lambda p, c, b, pg, s0, n: T.prefill_chunk(p, c, b, s0, n,
+                                                        cfg, pg)
         exp = expected_selects(cfg, n_tokens=seq)
         report.extend(lint_fn(
-            fn, params, batch, entry="prefill", expected=exp,
+            fn, params, paged_cache, batch, pages, start, ln,
+            entry="prefill", expected=exp,
             check_dense_fallback=_wants_dense_fallback_rule(cfg, seq),
             backend=backend, waivers=waivers))
 

@@ -11,9 +11,6 @@ Conventions:
 
 from __future__ import annotations
 
-import functools
-from typing import Dict, Optional, Tuple
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,18 +161,8 @@ def _flash_attn(q, k, v, scale, block: int, unroll: bool = False):
     return out.swapaxes(1, 2).astype(q.dtype)  # (B, S, H, Dh)
 
 
-def _gqa_forward(params, x, cfg, positions, quantize_kv: bool = False):
-    """Full causal self-attention. Returns (y, k_rows, v_rows) where
-    k_rows/v_rows are the roped true-head K/V — exactly what the decode
-    cache stores per position (the fused-prefill bulk write).
-
-    ``quantize_kv`` (int8 cache prefill): attention reads the
-    quantize→dequantize roundtrip of K/V instead of the exact rows —
-    the cache *representation* — so fused prefill sees exactly what
-    chunked prefill and every later decode step will read back, keeping
-    the contiguous engine a token-exact oracle for the paged one.
-    ``k_rows``/``v_rows`` stay exact: storage quantizes the originals.
-    """
+def gqa_apply(params, x, cfg, positions):
+    """Training forward (full causal self-attention)."""
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     hp = cfg.padded_heads
     sp = cfg.proj_sparsity
@@ -184,12 +171,6 @@ def _gqa_forward(params, x, cfg, positions, quantize_kv: bool = False):
     v = _split_heads(_proj_apply(params["v"], x, sp), hkv, dh)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    k_rows, v_rows = k, v
-    if quantize_kv:
-        kq, ks = _quant_rows(k)
-        vq, vs = _quant_rows(v)
-        k = kq.astype(x.dtype) * ks[..., None].astype(x.dtype)
-        v = vq.astype(x.dtype) * vs[..., None].astype(x.dtype)
     k = _repeat_kv(k, h // hkv)
     v = _repeat_kv(v, h // hkv)
     q, k, v = (_pad_heads(t, hp) for t in (q, k, v))
@@ -204,47 +185,7 @@ def _gqa_forward(params, x, cfg, positions, quantize_kv: bool = False):
         out = _causal_attn(q, k, v, scale)
     out = constrain(out, "batch", "seq", "heads", None)
     out = _mask_dummy_heads(out, cfg)
-    y = _o_proj(params["o"], out.reshape(*x.shape[:-1], hp * dh), sp)
-    return y, k_rows, v_rows
-
-
-def gqa_apply(params, x, cfg, positions):
-    """Training/prefill forward (full causal self-attention)."""
-    return _gqa_forward(params, x, cfg, positions)[0]
-
-
-def _pad_seq(x, max_seq: int):
-    """Zero-pad the sequence axis (1) out to ``max_seq``."""
-    s = x.shape[1]
-    if s >= max_seq:
-        return x[:, :max_seq]
-    pad = [(0, 0)] * x.ndim
-    pad[1] = (0, max_seq - s)
-    return jnp.pad(x, pad)
-
-
-def gqa_prefill(params, x, cfg, positions, max_seq: int):
-    """Fused full-sequence prefill: one forward over the whole prompt that
-    also emits the decode cache in bulk (rows [0, S) written at once).
-    Rows >= S are scratch — zeros here, but pad-token K/V when the caller
-    bucket-pads the prompt — and are only safe because decode overwrites
-    row ``pos`` before its validity mask ever reads it; no consumer may
-    assume they are meaningful (or zero).
-    With an int8 cache, attention reads the quantized representation
-    (see ``_gqa_forward(quantize_kv=...)``) so the fused path stays a
-    token-exact oracle for chunked paged prefill.
-    Returns (y, cache) with the same cache pytree as gqa_cache_init."""
-    int8 = getattr(cfg, "kv_cache_dtype", "") == "int8"
-    y, k, v = _gqa_forward(params, x, cfg, positions, quantize_kv=int8)
-    if int8:
-        kq, ks = _quant_rows(k)
-        vq, vs = _quant_rows(v)
-        cache = {"k": _pad_seq(kq, max_seq), "v": _pad_seq(vq, max_seq),
-                 "k_scale": _pad_seq(ks, max_seq),
-                 "v_scale": _pad_seq(vs, max_seq)}
-    else:
-        cache = {"k": _pad_seq(k, max_seq), "v": _pad_seq(v, max_seq)}
-    return y, cache
+    return _o_proj(params["o"], out.reshape(*x.shape[:-1], hp * dh), sp)
 
 
 def gqa_cache_init(cfg, batch: int, max_seq: int, dtype):
@@ -574,10 +515,9 @@ def _mla_expand(params, c_kv, cfg, ct):
     return k_nope, v
 
 
-def _mla_forward(params, x, cfg, positions):
-    """Full causal MLA forward in the expanded form (K and V up-projected
-    from the latent).  Returns (y, c_kv, k_pe) — the latent rows the
-    decode cache stores (fused-prefill bulk write)."""
+def mla_apply(params, x, cfg, positions):
+    """Training forward: full causal MLA in the expanded form (K and V
+    up-projected from the latent)."""
     h, dh, dr = cfg.n_heads, cfg.head_dim, cfg.rope_head_dim
     q_nope, q_pe, c_kv, k_pe = _mla_qkv(params, x, cfg, positions)
     k_nope, v = _mla_expand(params, c_kv, cfg, x.dtype)
@@ -591,12 +531,7 @@ def _mla_forward(params, x, cfg, positions):
                           unroll=cfg.unroll_inner)
     else:
         out = _causal_attn(q, k, v, scale)
-    y = out.reshape(*x.shape[:-1], h * dh) @ params["o"].astype(x.dtype)
-    return y, c_kv, k_pe
-
-
-def mla_apply(params, x, cfg, positions):
-    return _mla_forward(params, x, cfg, positions)[0]
+    return out.reshape(*x.shape[:-1], h * dh) @ params["o"].astype(x.dtype)
 
 
 def mla_cache_init(cfg, batch: int, max_seq: int, dtype):
@@ -608,13 +543,6 @@ def mla_cache_init(cfg, batch: int, max_seq: int, dtype):
 
 def mla_cache_specs():
     return {"ckv": ("batch", "kvseq", None), "kpe": ("batch", "kvseq", None)}
-
-
-def mla_prefill(params, x, cfg, positions, max_seq: int):
-    """Fused full-sequence MLA prefill: forward + bulk latent-cache write
-    (same contract as :func:`gqa_prefill`)."""
-    y, c_kv, k_pe = _mla_forward(params, x, cfg, positions)
-    return y, {"ckv": _pad_seq(c_kv, max_seq), "kpe": _pad_seq(k_pe, max_seq)}
 
 
 def _mla_cache_attn(params, x, q_nope, q_pe, ckv_view, kpe_view, valid, cfg):

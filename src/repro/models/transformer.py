@@ -14,16 +14,19 @@ Block kinds:
   mlstm/slstm — xLSTM mixers.
   shared_attn — weight-shared attention + FFN block (zamba2).
 
-Two entry points per workload:
-  :func:`loss_fn` / :func:`forward` — training & prefill (full sequence).
-  :func:`serve_step` + :func:`init_cache` — one-token decode with caches
-  (KV for attention; O(1) state for SSM blocks — the `long_500k` path).
+Entry points:
+  :func:`loss_fn` / :func:`forward` — training (full sequence).
+  :func:`serve_step` + :func:`init_paged_cache`, :func:`prefill_chunk` —
+  what the serving engine runs: paged decode and chunked prefill
+  (attention-only block patterns).
+  :func:`serve_step` + :func:`init_cache` — one-token decode over a
+  contiguous per-batch-row cache (KV for attention; O(1) state for SSM
+  blocks, which have no paged cache yet).
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -385,79 +388,6 @@ def copy_cache_page(cache, src, dst):
                         cache)
 
 
-def supports_fused_prefill(cfg) -> bool:
-    """Fused bulk-cache prefill exists for attention blocks; SSM/hybrid
-    patterns fall back to stepwise prefill (their decode state is the
-    *final* recurrence state, not per-position rows)."""
-    return all(k in ("attn", "shared_attn") for k in cfg.block_pattern)
-
-
-def _block_prefill(kind: str, params, x, cfg, positions, max_seq: int):
-    """Full-sequence forward that also emits the block's decode cache in
-    bulk. Returns (x, cache)."""
-    if kind not in ("attn", "shared_attn"):
-        raise NotImplementedError(
-            f"fused prefill not implemented for block kind {kind!r}; "
-            "use the stepwise prefill path")
-    h = rmsnorm_apply(params["norm1"], x, cfg.norm_eps)
-    pre = A.mla_prefill if cfg.use_mla else A.gqa_prefill
-    h, cache = pre(params["mixer"], h, cfg, positions, max_seq)
-    x = x + h
-    h = rmsnorm_apply(params["norm2"], x, cfg.norm_eps)
-    if "moe" in params:
-        h, _, _ = moe_apply(params["moe"], h, cfg, cfg.ffn_sparsity)
-        x = x + h
-    elif "ffn" in params:
-        x = x + ffn_apply(params["ffn"], h, cfg.ffn_sparsity, cfg.act)
-    return x, cache
-
-
-def prefill(params, batch, cfg, max_seq: int):
-    """Fused full-sequence prefill: ONE compiled call per prompt.
-
-    Runs the full forward over the prompt (B, S) while writing every
-    block's KV cache in bulk — rows [0, S) of a cache padded to
-    ``max_seq`` (rows >= S are zeros and are overwritten by decode before
-    any read; the validity mask in the decode steps never looks past the
-    current position).  The cache pytree matches :func:`init_cache`
-    exactly (leaves stacked over n_units), so the serving engine can
-    insert it into a slot of the live batch cache and hand off to
-    :func:`serve_step`.
-
-    Returns (logits (B, S, vocab), cache).
-    """
-    ct = dtype_of(cfg.compute_dtype)
-    x, _ = _embed_inputs(params, batch, cfg, ct)
-    b, s, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    shared = params.get("shared")
-    lead_caches = []
-    for i, p in enumerate(_lead_layers(params.get("lead"))):
-        with jax.named_scope(f"lead{i}"):
-            x, c = _block_prefill("attn", p, x, cfg, positions, max_seq)
-        lead_caches.append(c)
-
-    def unit_fn(x, unit_params):
-        caches = {}
-        for i, kind in enumerate(cfg.block_pattern):
-            p = shared if kind == "shared_attn" else unit_params[f"b{i}"]
-            with jax.named_scope(f"b{i}_{kind}"), \
-                    obs_sparsity.observe_site(f"b{i}"):
-                x, caches[f"b{i}"] = _block_prefill(kind, p, x, cfg,
-                                                    positions, max_seq)
-        # Same capture handoff as serve_step (empty tuple when inactive).
-        return x, (caches, obs_sparsity.drain_pending())
-
-    x, (cache, sparsity_aux) = lax.scan(unit_fn, x, params["units"])
-    obs_sparsity.emit_stacked(sparsity_aux)
-    if lead_caches:
-        cache["lead"] = _stack(lead_caches)
-    x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    table = (params["embed"] if cfg.tie_embeddings else params["head"])["table"]
-    logits = x @ table.astype(ct).T
-    return constrain(logits, "batch", "seq", "vocab"), cache
-
-
 def serve_step(params, cache, batch, pos, cfg, pages=None,
                moe_counts: bool = False):
     """Decode one token given caches of past state.
@@ -554,8 +484,7 @@ def prefill_chunk(params, cache, batch, pos_start, chunk_len, cfg, pages):
     """Forward ONE page-aligned prompt chunk of ONE slot through every
     block, scattering its KV rows into the slot's page chains (the paged
     layout's incremental prefill — long prompts run as a sequence of
-    these interleaved with decode steps instead of one monolithic
-    :func:`prefill` call).
+    these interleaved with decode steps).
 
     batch: {"tokens": (1, C)}; pages: (1, n_blocks) int32 — the
     prefilling slot's page table; pos_start / chunk_len: traced scalars,

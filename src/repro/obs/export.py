@@ -5,12 +5,10 @@ Three consumers, three forms:
 * **JSONL event log** (:class:`JsonlWriter`) — an append-only stream of
   one-line JSON events (spans from :mod:`repro.obs.trace`, per-request
   lifecycle records from :mod:`repro.runtime.scheduler`, a final metrics
-  snapshot).  The CI ``telemetry-smoke`` step validates this file with
-  ``python -m repro.obs.export --validate PATH``.
+  snapshot), checked by ``python -m repro.obs.export --validate PATH``.
 * **End-of-run snapshot dict** — ``Engine.metrics_snapshot()`` returns a
   nested dict; :func:`latency_columns` / :func:`sparsity_columns` distill
-  it into the flat columns ``benchmarks/run.py --json`` rows carry
-  (``BENCH_serve.json`` schema v2).
+  it into flat latency and sparsity columns.
 * **Live polling** — the same snapshot dict, callable mid-run.
 
 Event schema (one object per line; extra keys are allowed, types of the
@@ -148,7 +146,7 @@ def validate_jsonl(path: str, max_errors: int = 20
 
 
 # ---------------------------------------------------------------------------
-# Snapshot -> flat bench columns (BENCH_serve.json schema v2)
+# Snapshot -> flat latency and sparsity columns
 # ---------------------------------------------------------------------------
 
 def _ms(v: Optional[float]) -> Optional[float]:

@@ -5,7 +5,7 @@ against a pool of fixed-size pages (:class:`BlockAllocator`), every
 slot addresses its pages through a per-slot page table threaded into
 the decode jit (:mod:`repro.runtime.kvcache.layout`), and long prompts
 prefill in page-aligned chunks interleaved with decode steps
-(``Engine(kv_layout="paged")`` in :mod:`repro.launch.serve`).
+(``Engine`` in :mod:`repro.launch.serve`).
 
 See ``src/repro/runtime/README.md`` for the layout, admission policy,
 and chunked-prefill schedule.

@@ -6,18 +6,13 @@ pages of ``page_size`` token rows each; a request holds a *chain* — an
 ordered list of page ids — and logical slot position ``p`` lives in
 chain page ``p // page_size`` at row ``p % page_size``.
 
-Two admission policies sit on top of this allocator (the scheduler
-chooses; see ``runtime/scheduler.py``):
-
-* **reserve-on-admit** (the PR 9 oracle): the chain covers the
-  worst-case length ``prompt + max_new_tokens`` in full at admission,
-  so decode can never run dry mid-request.
-* **grow-on-demand** (the default serving policy): the chain covers
-  only ``pages_needed(len(prompt))`` at admission and
-  :meth:`BlockAllocator.extend` appends decode pages lazily at page
-  boundaries; pool exhaustion is handled by preemption
-  (recompute-on-resume) in the serve loop, not by head-of-line
-  over-reservation.
+Admission on top of this allocator is grow-on-demand (see
+``runtime/scheduler.py``): the chain covers only
+``pages_needed(len(prompt))`` at admission and
+:meth:`BlockAllocator.extend` appends decode pages lazily at page
+boundaries; pool exhaustion is handled by preemption
+(recompute-on-resume) in the serve loop, not by head-of-line
+over-reservation.
 
 Design points:
 

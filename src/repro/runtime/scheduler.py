@@ -154,44 +154,32 @@ class Scheduler:
     """FIFO admission into a fixed pool of decode slots.
 
     With ``allocator`` (a :class:`repro.runtime.kvcache.BlockAllocator`)
-    admission is additionally gated on KV pages, under one of two
-    policies (``kv_policy``):
+    admission is additionally gated on KV pages (grow-on-demand): the
+    queue head needs ``pages_needed(len(prompt))`` free — minus any
+    prompt-prefix pages already live in the allocator's prefix index,
+    which are adopted by reference (``serve.prefix_hit_pages``).  Decode
+    pages are allocated lazily by the engine (``BlockAllocator.extend``
+    at page boundaries); when the pool runs dry the engine preempts the
+    youngest-admitted slot (:meth:`preemption_victim` / :meth:`preempt`
+    — recompute-on-resume: pages released, request re-queued at the
+    head with its generated tokens appended to the prompt, sampling
+    state stashed so greedy AND stochastic decoding resume
+    token-exactly).
 
-    * ``"reserve"`` (reserve-on-admit, the PR 9 oracle): the queue head
-      needs its worst-case footprint
-      ``pages_needed(len(prompt) + max_new_tokens)`` free, reserved in
-      full at admit, so decode can never run out of pages mid-request.
-    * ``"grow"`` (grow-on-demand): the head needs only
-      ``pages_needed(len(prompt))`` — minus any prompt-prefix pages
-      already live in the allocator's prefix index, which are adopted
-      by reference (``serve.prefix_hit_pages``).  Decode pages are
-      allocated lazily by the engine (``BlockAllocator.extend`` at page
-      boundaries); when the pool runs dry the engine preempts the
-      youngest-admitted slot (:meth:`preemption_victim` /
-      :meth:`preempt` — recompute-on-resume: pages released, request
-      re-queued at the head with its generated tokens appended to the
-      prompt, sampling state stashed so greedy AND stochastic decoding
-      resume token-exactly).
-
-    Strict FIFO either way: a blocked head blocks everything behind it
+    Strict FIFO: a blocked head blocks everything behind it
     (no starvation of long prompts by short ones), and preemption evicts
     youngest-first, so a re-queued victim is still older than everything
     behind it.  Retirement releases the chain copy-free.
     """
 
-    def __init__(self, n_slots: int, telemetry=None, allocator=None,
-                 kv_policy: str = "reserve"):
+    def __init__(self, n_slots: int, telemetry=None, allocator=None):
         if n_slots < 1:
             raise ValueError("n_slots must be >= 1")
-        if kv_policy not in ("reserve", "grow"):
-            raise ValueError(
-                f"kv_policy must be 'reserve' or 'grow', got {kv_policy!r}")
         if telemetry is None:
             from repro.obs import Telemetry
             telemetry = Telemetry.off()
         self.telemetry = telemetry
         self.allocator = allocator
-        self.kv_policy = kv_policy
         self.slots: List[Slot] = [Slot(i) for i in range(n_slots)]
         self.queue: Deque[Request] = deque()
         self.finished: Dict[int, List[int]] = {}
@@ -265,22 +253,20 @@ class Scheduler:
         slots sit out until their last chunk lands)."""
         return [s for s in self.slots if s.busy and not s.prefilling]
 
-    def admit(self, now: float = 0.0, chunked: bool = False) -> List[Slot]:
+    def admit(self, now: float = 0.0) -> List[Slot]:
         """Move queued requests into free slots (FIFO). Returns the slots
         that were (re)filled this call; the engine prefills each one.
 
-        ``chunked=True`` admits with ``prefill_pos = 0`` (the engine
-        feeds the prompt as paged chunks and advances ``prefill_pos``);
-        otherwise the prompt is assumed fused-prefilled at admit, as
-        before.  With an allocator, the queue head must also fit the
-        free pages (strict FIFO — a blocked head blocks the rest):
-        its worst-case footprint under ``kv_policy="reserve"``, just
-        its prompt under ``"grow"`` — where prompt-prefix pages already
-        in the allocator's index are adopted by reference and skipped
-        by chunked prefill (``prefill_pos`` starts past them, capped at
-        ``len(prompt) - 1`` so the final logits row is always produced
-        by a real chunk forward — an exact-duplicate prompt re-runs its
-        last token, whose shared-page write the engine breaks with
+        A slot is admitted with ``prefill_pos = 0``: the engine feeds the
+        prompt as paged chunks and advances ``prefill_pos``.  With an
+        allocator, the queue head must also fit the free pages (strict
+        FIFO — a blocked head blocks the rest): its prompt's pages, less
+        the prompt-prefix pages already in the allocator's index, which
+        are adopted by reference and skipped by chunked prefill
+        (``prefill_pos`` starts past them, capped at ``len(prompt) - 1``
+        so the final logits row is always produced by a real chunk
+        forward — an exact-duplicate prompt re-runs its last token,
+        whose shared-page write the engine breaks with
         copy-on-write)."""
         admitted = []
         for slot in self.slots:
@@ -290,26 +276,16 @@ class Scheduler:
             shared_rows = 0
             if self.allocator is not None:
                 a = self.allocator
-                if self.kv_policy == "grow":
-                    shared = []
-                    if chunked:
-                        shared = a.match_prefix(
-                            prefix_keys(req.prompt, a.page_size))
-                    need = a.pages_needed(len(req.prompt)) - len(shared)
-                    if not a.can_allocate(need):
-                        break  # head-of-line blocking: keep FIFO order
-                    a.allocate(req.uid, need, shared=shared)
-                    if shared:
-                        self._c_prefix_hits.inc(len(shared))
-                        self.prefix_hit_pages += len(shared)
-                        shared_rows = min(len(shared) * a.page_size,
-                                          len(req.prompt) - 1)
-                else:
-                    need = a.pages_needed(
-                        len(req.prompt) + req.max_new_tokens)
-                    if not a.can_allocate(need):
-                        break  # head-of-line blocking: keep FIFO order
-                    a.allocate(req.uid, need)
+                shared = a.match_prefix(prefix_keys(req.prompt, a.page_size))
+                need = a.pages_needed(len(req.prompt)) - len(shared)
+                if not a.can_allocate(need):
+                    break  # head-of-line blocking: keep FIFO order
+                a.allocate(req.uid, need, shared=shared)
+                if shared:
+                    self._c_prefix_hits.inc(len(shared))
+                    self.prefix_hit_pages += len(shared)
+                    shared_rows = min(len(shared) * a.page_size,
+                                      len(req.prompt) - 1)
             self.queue.popleft()
             slot.request = req
             slot.pos = len(req.prompt)
@@ -318,7 +294,7 @@ class Scheduler:
             slot.admit_time = now
             slot.first_token_time = 0.0
             slot.last_token_time = 0.0
-            slot.prefill_pos = shared_rows if chunked else len(req.prompt)
+            slot.prefill_pos = shared_rows
             slot.admit_seq = self._admit_seq
             self._admit_seq += 1
             resume = self._resume.pop(req.uid, None)
@@ -334,7 +310,7 @@ class Scheduler:
         self._update_page_gauges()
         return admitted
 
-    # -- preemption (kv_policy="grow") --------------------------------------
+    # -- preemption ---------------------------------------------------------
     def preemption_victim(self, exclude: Sequence[int] = ()) -> \
             Optional[Slot]:
         """The youngest-admitted busy slot (highest ``admit_seq``) not in

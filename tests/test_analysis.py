@@ -34,6 +34,17 @@ def test_decode_prefill_zero_findings():
     assert report.ok, report.render()
 
 
+def test_deepseek_prefill_chunk_zero_findings():
+    """The served chunk program of a reduced deepseek-v2-lite — latent
+    attention over the paged cache, the held experts and the leading
+    dense layer — stages no finding."""
+    cfg = get_config("deepseek_v2_lite_16b").reduced()
+    assert cfg.use_mla and cfg.is_moe and cfg.n_dense_layers
+    report = lint_config(cfg, entries=("prefill",), check_hlo=False)
+    assert report.entries == ["prefill"]
+    assert report.ok, report.render()
+
+
 def test_decode_hlo_zero_findings():
     """AOT-compile the reduced decode step; the compiled module must stage
     no host transfers and no collectives (single-process)."""

@@ -6,17 +6,15 @@ share of the experts, dropless routing; and what the change must leave
 as it was (Qwen3's router, smollm's dispatch sites)."""
 
 import dataclasses
-import importlib.util
-import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _reference as R
 from repro.configs import get_config
-from repro.core.api import SparsityConfig, observe_dispatch
+from repro.core.api import observe_dispatch
 from repro.launch.mesh import make_mesh
 from repro.launch.serve import Engine
 from repro.models import attention as A
@@ -25,42 +23,9 @@ from repro.models import transformer as T
 from repro.obs import DispatchStats
 from repro.runtime.scheduler import Request
 
-CHIP = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 HI = jax.lax.Precision.HIGHEST
-
-
-def _load(name):
-    path = CHIP / "configs" / name
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + name.replace("-", "_").replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF = _load("deepseek-v2-lite.reference.py")
-MODEL = json.loads((CHIP / "configs" / "deepseek-v2-lite.json").read_text())[
-    "model"]
-#: Small widths; 1 dense layer and 2 expert layers holding experts [3, 5)
-#: of 8, 2 a token; float32 compute so that the program meets the
-#: reference to rounding.
-SMALL = dict(n_layers=3, n_dense_layers=1, d_model=64, n_heads=4,
-             n_kv_heads=4, d_head=16, d_ff=32, dense_d_ff=96,
-             vocab_size=256, n_experts=8, held_experts=2,
-             held_expert_start=3, experts_per_token=2, n_shared_experts=2,
-             kv_lora_rank=32, rope_head_dim=8, compute_dtype="float32",
-             kv_cache_dtype="float32")
-
-
-def small(**over):
-    m = dict(MODEL, **dict(SMALL, **over))
-    cfg = dataclasses.replace(
-        get_config("deepseek_v2_lite_16b"),
-        **{k: (tuple(v) if k == "block_pattern" else
-               SparsityConfig(**v) if k == "ffn_sparsity" else v)
-           for k, v in m.items()}, remat=False)
-    params = REF.make_weights(jax.random.PRNGKey(7), m)
-    return m, cfg, params
+REF = R.reference("deepseek-v2-lite")
+small = R.deepseek_small
 
 
 def ref_logits(params, tokens, m):
@@ -115,8 +80,7 @@ def test_engine_serves_the_reference_greedy_tokens():
                     max_new_tokens=g)
             for i, (n, g) in enumerate([(11, 5), (4, 6), (19, 4)])]
     eng = Engine(cfg, make_mesh((1, 1), ("data", "model")), max_seq=32,
-                 n_slots=2, params=params, kv_layout="paged", page_size=8,
-                 prefill_chunk=8)
+                 n_slots=2, params=params, page_size=8, prefill_chunk=8)
     out, _ = eng.serve(reqs)
     for r in reqs:
         seq = r.prompt + out[r.uid]
@@ -133,7 +97,7 @@ def test_absorbed_decode_matches_expanded_attention():
     mp = jax.tree.map(lambda a: a[0], params["lead"]["mixer"])
     s = 9
     x = jax.random.normal(jax.random.PRNGKey(3), (2, s, cfg.d_model))
-    full, _, _ = A._mla_forward(mp, x, cfg, jnp.broadcast_to(
+    full = A.mla_apply(mp, x, cfg, jnp.broadcast_to(
         jnp.arange(s), (2, s)))
     cache = A.mla_cache_init(cfg, 2, s, jnp.float32)
     for pos in range(s):
@@ -252,24 +216,19 @@ def test_prefix_sharing_and_cow_cover_the_leading_layer():
     """A duplicate prompt admitted after its parent's prefill adopts the
     parent's pages (the leading layer's pool leaf with the scanned ones)
     and breaks the last one by copy-on-write; an extension adopts them
-    too.  The tokens equal the contiguous engine's."""
-    _, cfg, params = small()
+    too.  Every served token is the reference's first choice."""
+    m, cfg, params = small()
     base = np.random.default_rng(7).integers(0, cfg.vocab_size, 12).tolist()
-
-    def reqs():
-        # the parent decodes long enough to stay alive while the others
-        # land; two budget-1 fillers hold the other slots until the
-        # parent's pages are registered
-        spec = [(base, 6), ([5, 6, 7], 1), ([8, 9, 10], 1), (base, 6),
-                (base + [3, 1, 4], 5)]
-        return [Request(uid=i, prompt=list(p), max_new_tokens=g)
-                for i, (p, g) in enumerate(spec)]
-
+    # the parent decodes long enough to stay alive while the others land;
+    # two budget-1 fillers hold the other slots until the parent's pages
+    # are registered
+    spec = [(base, 6), ([5, 6, 7], 1), ([8, 9, 10], 1), (base, 6),
+            (base + [3, 1, 4], 5)]
+    reqs = [Request(uid=i, prompt=list(p), max_new_tokens=g)
+            for i, (p, g) in enumerate(spec)]
     mesh = make_mesh((1, 1), ("data", "model"))
-    out_c, _ = Engine(cfg, mesh, max_seq=32, n_slots=3,
-                      params=params).serve(reqs())
-    out_p, stats = Engine(cfg, mesh, max_seq=32, n_slots=3, params=params,
-                          kv_layout="paged", page_size=4,
-                          prefill_chunk=4).serve(reqs())
+    out, stats = Engine(cfg, mesh, max_seq=32, n_slots=3, params=params,
+                        page_size=4, prefill_chunk=4).serve(reqs)
     assert stats["prefix_hit_pages"] >= 3 and stats["cow_copies"] >= 1, stats
-    assert out_p == out_c
+    assert [len(out[r.uid]) for r in reqs] == [g for _, g in spec]
+    R.assert_greedy("deepseek-v2-lite", params, m, reqs, out, 32)

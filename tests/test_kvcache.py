@@ -1,8 +1,9 @@
 """Paged KV-cache tests: block-allocator invariants (unit + property),
-PagedKV geometry/layout ops, page-gated admission policy, and token-exact
-parity of the paged engine against the contiguous oracle — including
-chunked prefill of prompts longer than one chunk and a page pool smaller
-than full backing.
+PagedKV geometry/layout ops, page-gated admission policy, and the paged
+engine's greedy tokens against the plain reference — including chunked
+prefill of prompts longer than one chunk, a page pool smaller than full
+backing, the latent cache of MLA, and the int8 cache (against stepwise
+decode, which the reference cannot judge).
 
 ISSUE 10 additions: the grow-on-demand path (lazy ``extend`` at page
 boundaries, LRU preemption with recompute-on-resume, ref-counted
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _reference as R
 from _hypothesis_compat import given, settings, st
 from repro.configs import get_config
 from repro.launch.mesh import make_mesh
@@ -389,8 +391,9 @@ def _req(uid, p_len, max_new=4, **kw):
 def test_admission_gated_by_free_pages_not_slots():
     alloc = BlockAllocator(n_pages=5, page_size=4)     # 4 usable pages
     s = Scheduler(4, allocator=alloc)
-    s.submit_many([_req(0, 8, max_new=4),   # 3 pages
-                   _req(1, 1, max_new=3)])  # 1 page
+    # admission charges the prompt's pages only
+    s.submit_many([_req(0, 12, max_new=4),  # 3 pages
+                   _req(1, 3, max_new=8)])  # 1 page
     admitted = s.admit()
     assert [sl.request.uid for sl in admitted] == [0, 1]
     assert alloc.free_pages == 0
@@ -410,7 +413,7 @@ def test_admission_gated_by_free_pages_not_slots():
 def test_admission_head_of_line_blocks_fifo():
     alloc = BlockAllocator(n_pages=4, page_size=2)     # 3 usable pages
     s = Scheduler(2, allocator=alloc)
-    s.submit_many([_req(0, 8, max_new=2),   # 5 pages: never fits now
+    s.submit_many([_req(0, 8, max_new=2),   # 4 prompt pages: never fits
                    _req(1, 1, max_new=1)])  # 1 page: would fit
     assert s.admit() == []                  # strict FIFO: head blocks tail
     assert [r.uid for r in s.queue] == [0, 1]
@@ -420,7 +423,7 @@ def test_admission_head_of_line_blocks_fifo():
 def test_chunked_admit_sets_prefill_state():
     s = Scheduler(1, allocator=BlockAllocator(8, 2))
     s.submit(_req(0, 5))
-    (slot,) = s.admit(chunked=True)
+    (slot,) = s.admit()
     assert slot.prefilling and slot.prefill_pos == 0
     assert s.decoding_slots() == []
     slot.prefill_pos = 5                    # engine finished the chunks
@@ -434,11 +437,11 @@ def test_chunked_admit_sets_prefill_state():
 
 def test_grow_admission_uses_prompt_footprint_only():
     alloc = BlockAllocator(n_pages=5, page_size=4)   # 4 usable pages
-    s = Scheduler(4, allocator=alloc, kv_policy="grow")
+    s = Scheduler(4, allocator=alloc)
     # worst-case footprints are 3+3 pages (would NOT both fit under
     # reserve); prompt footprints are 2+1 and fit together under grow
     s.submit_many([_req(0, 8, max_new=4), _req(1, 1, max_new=8)])
-    admitted = s.admit(chunked=True)
+    admitted = s.admit()
     assert [sl.request.uid for sl in admitted] == [0, 1]
     assert alloc.chain_len(0) == 2 and alloc.chain_len(1) == 1
     assert alloc.free_pages == 1
@@ -447,9 +450,9 @@ def test_grow_admission_uses_prompt_footprint_only():
 
 def test_preemption_victim_is_youngest_admitted():
     alloc = BlockAllocator(n_pages=9, page_size=4)
-    s = Scheduler(3, allocator=alloc, kv_policy="grow")
+    s = Scheduler(3, allocator=alloc)
     s.submit_many([_req(0, 4), _req(1, 4), _req(2, 4)])
-    s.admit(chunked=True)
+    s.admit()
     victim = s.preemption_victim()
     assert victim.request.uid == 2          # last admitted, least service
     assert s.preemption_victim(exclude=(victim.index,)).request.uid == 1
@@ -457,10 +460,10 @@ def test_preemption_victim_is_youngest_admitted():
 
 def test_preempt_requeues_at_head_with_generated_suffix():
     alloc = BlockAllocator(n_pages=9, page_size=4)
-    s = Scheduler(2, allocator=alloc, kv_policy="grow")
+    s = Scheduler(2, allocator=alloc)
     s.submit_many([_req(0, 4, max_new=6), _req(1, 3, max_new=2),
                    _req(2, 2, max_new=2)])
-    s.admit(chunked=True)
+    s.admit()
     slot = s.slots[0]
     slot.prefill_pos = 4                    # prefill done
     for t in (11, 12, 13):
@@ -479,7 +482,7 @@ def test_preempt_requeues_at_head_with_generated_suffix():
     alloc.check()
     # re-admission restores generated tokens and the sampling rng, so
     # decode continues exactly where it left off
-    (slot2,) = s.admit(chunked=True)
+    (slot2,) = s.admit()
     assert slot2.request.uid == 0
     assert slot2.generated == [11, 12, 13]
     assert slot2.rng.bit_generator.state == rng_state
@@ -500,10 +503,10 @@ def test_preempt_twice_rebuilds_from_original_prompt():
     ``slot.generated`` to it again duplicated that round (corrupt KV
     context, wrong positions, possible max_seq overflow)."""
     alloc = BlockAllocator(n_pages=17, page_size=4)
-    s = Scheduler(1, allocator=alloc, kv_policy="grow")
+    s = Scheduler(1, allocator=alloc)
     orig = _req(0, 4, max_new=8)
     s.submit(orig)
-    s.admit(chunked=True)
+    s.admit()
     slot = s.slots[0]
     slot.prefill_pos = 4
     for t in (11, 12):
@@ -512,7 +515,7 @@ def test_preempt_twice_rebuilds_from_original_prompt():
     assert list(s.queue[0].prompt) == list(orig.prompt) + [11, 12]
     # resume, generate two more, preempt AGAIN: the rebuilt prompt must
     # hold each generated token exactly once
-    (slot,) = s.admit(chunked=True)
+    (slot,) = s.admit()
     slot.prefill_pos = len(slot.request.prompt)
     for t in (13, 14):
         s.record_token(slot, t)
@@ -522,7 +525,7 @@ def test_preempt_twice_rebuilds_from_original_prompt():
     assert s.records[0].preemptions == 2
     alloc.check()
     # third leg runs to completion against the ORIGINAL budget
-    (slot,) = s.admit(chunked=True)
+    (slot,) = s.admit()
     assert slot.generated == [11, 12, 13, 14]
     for t in (15, 16, 17, 18):
         s.record_token(slot, t)
@@ -534,15 +537,15 @@ def test_preempt_twice_rebuilds_from_original_prompt():
 
 def test_grow_admission_adopts_registered_prefix_pages():
     alloc = BlockAllocator(n_pages=9, page_size=2)
-    s = Scheduler(2, allocator=alloc, kv_policy="grow")
+    s = Scheduler(2, allocator=alloc)
     parent = _req(0, 6, max_new=2)
     s.submit(parent)
-    s.admit(chunked=True)
+    s.admit()
     # engine finished the parent's prefill and published its pages
     alloc.register_chain_prefix(0, prefix_keys(parent.prompt, 2))
     dup = _req(1, 6, max_new=2)             # same prompt (same _req range)
     s.submit(dup)
-    (slot,) = s.admit(chunked=True)
+    (slot,) = s.admit()
     assert slot.request.uid == 1
     assert alloc.chain(1) == alloc.chain(0)  # all 3 pages adopted
     assert s.prefix_hit_pages == 3
@@ -554,7 +557,7 @@ def test_grow_admission_adopts_registered_prefix_pages():
 
 
 # ---------------------------------------------------------------------------
-# paged engine == contiguous engine, token for token
+# the paged engine against the plain reference
 # ---------------------------------------------------------------------------
 
 def _cfg(**overrides):
@@ -571,23 +574,39 @@ def _mixed_requests(cfg, plens, gens):
             for i, (n, g) in enumerate(zip(plens, gens))]
 
 
+def _stepwise_greedy(params, cfg, prompt, n_new, max_seq):
+    """Greedy tokens of one request decoded a position at a time through
+    the model-level contiguous cache (``serve_step`` over
+    ``init_cache``)."""
+    step = jax.jit(lambda p, c, t, q: T.serve_step(p, c, {"tokens": t}, q,
+                                                    cfg))
+    cache, _ = T.init_cache(cfg, 1, max_seq)
+    seq, out = list(prompt), []
+    for pos in range(len(prompt) + n_new - 1):
+        logits, cache = step(params, cache, jnp.asarray([[seq[pos]]]),
+                             jnp.int32(pos))
+        if pos >= len(prompt) - 1:
+            out.append(int(np.argmax(logits[0])))
+            seq.append(out[-1])
+    return out
+
+
 def test_paged_engine_matches_contiguous_mixed_lengths():
     """The acceptance-criteria workload: 8 requests over 4 slots, mixed
     prompt/gen lengths (several prompts span multiple prefill chunks),
-    greedy sampling — the paged engine must emit identical tokens, with
-    a pool SMALLER than full backing so admission really gates on pages
-    and reclamation really recycles them."""
-    cfg = _cfg()
+    greedy sampling, with a pool SMALLER than full backing so admission
+    really gates on pages and reclamation really recycles them: every
+    served token is the reference's first choice."""
+    m, cfg, params = R.smollm_small()
     mesh = make_mesh((1, 1), ("data", "model"))
     plens = [5, 19, 3, 26, 9, 14, 7, 22]
     gens = [6, 7, 8, 9, 10, 6, 7, 8]
-    eng_c = Engine(cfg, mesh, max_seq=40, n_slots=4)
-    out_c, _ = eng_c.serve(_mixed_requests(cfg, plens, gens))
-    eng_p = Engine(cfg, mesh, max_seq=40, n_slots=4, kv_layout="paged",
-                   page_size=8, n_pages=13, prefill_chunk=8,
-                   params=eng_c.params)
-    out_p, stats = eng_p.serve(_mixed_requests(cfg, plens, gens))
-    assert out_p == out_c
+    eng = Engine(cfg, mesh, max_seq=40, n_slots=4, page_size=8, n_pages=13,
+                 prefill_chunk=8, params=params)
+    reqs = _mixed_requests(cfg, plens, gens)
+    out, stats = eng.serve(reqs)
+    assert [len(out[i]) for i in range(8)] == gens
+    R.assert_greedy("smollm-360m", params, m, reqs, out, 40)
     # prompts of 19/26/22 tokens took 3/4/3 chunks of 8 — prefill really
     # was chunked, not one monolithic call per prompt
     expected_chunks = sum(-(-n // 8) for n in plens)
@@ -597,49 +616,53 @@ def test_paged_engine_matches_contiguous_mixed_lengths():
 
 def test_paged_engine_int8_cache_variant():
     """The quantized-cache leaves (int8 rows + fp32 scales) go through
-    the same generic gather/scatter; parity must hold there too."""
-    cfg = _cfg(kv_cache_dtype="int8")
+    the same generic gather/scatter.  The reference keeps no cache, so
+    the tokens are held to stepwise greedy decode over the model-level
+    int8 cache, which reads the same quantized rows."""
+    _, cfg, params = R.smollm_small(kv_cache_dtype="int8")
     mesh = make_mesh((1, 1), ("data", "model"))
     plens, gens = [11, 4, 17, 6], [5, 6, 5, 6]
-    eng_c = Engine(cfg, mesh, max_seq=32, n_slots=2)
-    out_c, _ = eng_c.serve(_mixed_requests(cfg, plens, gens))
-    eng_p = Engine(cfg, mesh, max_seq=32, n_slots=2, kv_layout="paged",
-                   page_size=8, prefill_chunk=8, params=eng_c.params)
-    out_p, _ = eng_p.serve(_mixed_requests(cfg, plens, gens))
-    assert out_p == out_c
+    eng = Engine(cfg, mesh, max_seq=32, n_slots=2, page_size=8,
+                 prefill_chunk=8, params=params)
+    reqs = _mixed_requests(cfg, plens, gens)
+    out, _ = eng.serve(reqs)
+    for r in reqs:
+        assert out[r.uid] == _stepwise_greedy(
+            params, cfg, r.prompt, r.max_new_tokens, 32)
 
 
 def test_paged_engine_mla_cache_variant():
     """MLA latent caches (kv_lora + rope leaves instead of per-head K/V)
-    page through the same generic gather/scatter; parity must hold with
-    the compressed-cache leaf shapes too."""
-    cfg = get_config("deepseek_v2_lite_16b").reduced(
-        remat=False, n_experts=0, n_shared_experts=0, experts_per_token=0,
-        d_ff=64, head_pad=0, compute_dtype="float32", param_dtype="float32")
+    page through the same generic gather/scatter: deepseek-v2-lite at
+    small widths, its leading dense layer and held experts included,
+    serves the reference's greedy tokens."""
+    m, cfg, params = R.deepseek_small()
     mesh = make_mesh((1, 1), ("data", "model"))
     plens, gens = [11, 4, 17, 6], [5, 6, 5, 6]
-    eng_c = Engine(cfg, mesh, max_seq=32, n_slots=2)
-    out_c, _ = eng_c.serve(_mixed_requests(cfg, plens, gens))
-    eng_p = Engine(cfg, mesh, max_seq=32, n_slots=2, kv_layout="paged",
-                   page_size=8, prefill_chunk=8, params=eng_c.params)
-    out_p, _ = eng_p.serve(_mixed_requests(cfg, plens, gens))
-    assert out_p == out_c
+    eng = Engine(cfg, mesh, max_seq=32, n_slots=2, page_size=8,
+                 prefill_chunk=8, params=params)
+    reqs = _mixed_requests(cfg, plens, gens)
+    out, _ = eng.serve(reqs)
+    R.assert_greedy("deepseek-v2-lite", params, m, reqs, out, 32)
 
 
 def test_paged_serve_rejects_oversized_request():
     cfg = _cfg()
     mesh = make_mesh((1, 1), ("data", "model"))
-    eng = Engine(cfg, mesh, max_seq=16, n_slots=2, kv_layout="paged",
-                 page_size=8)
+    eng = Engine(cfg, mesh, max_seq=16, n_slots=2, page_size=8)
     with pytest.raises(ValueError, match="max_seq"):
         eng.serve([_req(0, 10, max_new=10)])  # 20 rows > max_seq 16
 
 
-def test_engine_rejects_bad_layout():
-    cfg = _cfg()
+@pytest.mark.parametrize("option", [
+    {"kv_layout": "ragged"}, {"kv_layout": "contiguous"},
+    {"kv_policy": "reserve"}], ids=["ragged", "contiguous", "reserve"])
+def test_engine_rejects_bad_layout(option):
+    """One layout and one admission policy: any other value is refused,
+    never quietly served paged."""
     mesh = make_mesh((1, 1), ("data", "model"))
-    with pytest.raises(ValueError, match="kv_layout"):
-        Engine(cfg, mesh, max_seq=16, kv_layout="ragged")
+    with pytest.raises(ValueError, match=next(iter(option))):
+        Engine(_cfg(), mesh, max_seq=16, **option)
 
 
 def test_init_paged_cache_requires_attention_pattern():

@@ -35,12 +35,11 @@ CONFIGS = {
 }
 
 
-def _engine(name, layout="paged", **cfg_kw):
+def _engine(name, **cfg_kw):
     tel = Telemetry.on(sparsity_every=0)
     mesh = make_mesh((1, 1), ("data", "model"))
     eng = Engine(CONFIGS[name](**cfg_kw), mesh, max_seq=64, n_slots=3,
-                 telemetry=tel, kv_layout=layout, page_size=8,
-                 prefill_chunk=16)
+                 telemetry=tel, page_size=8, prefill_chunk=16)
     return eng, tel
 
 
@@ -197,15 +196,14 @@ def test_served_logits_equal_the_masters_to_the_bit(served):
         np.testing.assert_array_equal(g, w)
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
 @pytest.mark.parametrize("name", sorted(CONFIGS))
-def test_served_greedy_tokens_equal_the_masters(name, layout):
+def test_served_greedy_tokens_equal_the_masters(name):
     rng = np.random.default_rng(5)
     reqs = lambda vocab: [Request(uid=i, prompt=rng.integers(
         0, vocab, n).tolist(), max_new_tokens=g)
         for i, (n, g) in enumerate(((21, 6), (5, 9), (33, 4), (12, 7)))]
-    eng, _ = _engine(name, layout)
-    master, _ = _engine(name, layout)
+    eng, _ = _engine(name)
+    master, _ = _engine(name)
     master.served_params = master.params    # the step as it was
     assert jax.tree.leaves(eng.served_params)[0].dtype == BF16
     vocab = eng.cfg.vocab_size
@@ -365,8 +363,7 @@ def test_compiled_decode_step_converts_no_f32_weight(served):
 def test_served_copies_keep_the_masters_sharding():
     """On a (1, 4) mesh each copy is laid out as its master leaf is."""
     mesh = make_mesh((1, 4), ("data", "model"))
-    eng = Engine(CONFIGS["smollm"](), mesh, max_seq=32, n_slots=2,
-                 kv_layout="paged")
+    eng = Engine(CONFIGS["smollm"](), mesh, max_seq=32, n_slots=2)
     pairs = list(zip(jax.tree.leaves(eng.params),
                      jax.tree.leaves(eng.served_params)))
     assert any(s.dtype == BF16 for _, s in pairs)
