@@ -280,9 +280,58 @@ def packed_conv2d_init(key, kh: int, kw: int, c_in: int, c_out: int,
     return params, specs
 
 
+def packed_conv2d_mxu(params, x, kh: int, kw: int, stride: int = 1,
+                      padding: str = "VALID"):
+    """The packed conv as one f32 convolution over its decompressed kernel.
+
+    The dense masked weight (1/N nonzero) is reshaped to HWIO: im2col's
+    column order (i, j, c) is exactly that reshape.  Rows past
+    ``kh*kw*C`` met zero padding, and outputs past the bias are sliced
+    off, so the kernel drops both before the convolution.  Every product
+    is the faithful path's, at ``Precision.HIGHEST``; only the order of
+    the sums differs.  Autodiff transposes the decompression into a
+    gather onto the packed support, so gradients stay masked."""
+    c_in = x.shape[-1]
+    w = F.decompress(params["packed"].astype(x.dtype), params["route"])
+    w = w[:kh * kw * c_in]
+    if "b" in params:
+        w = w[:, :params["b"].shape[0]]
+    w = w.reshape(kh, kw, c_in, w.shape[1])
+    ph, pw = (kh // 2, kw // 2) if padding == "SAME" else (0, 0)
+    y = jax.lax.conv_general_dilated(
+        x, w, (stride, stride), ((ph, ph), (pw, pw)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(x.dtype)
+    if "b" in params:
+        y = y + params["b"].astype(x.dtype)
+    return y
+
+
 def packed_conv2d_apply(params, x, cfg: SparsityConfig, kh: int, kw: int,
                         stride: int = 1, padding: str = "VALID",
                         x_is_sparse: bool = False):
+    """CS conv: im2col, then the packed linear map over each receptive
+    field (paper Fig. 7).
+
+    On a TPU the layer runs :func:`packed_conv2d_mxu` instead (path
+    ``conv_mxu``): the faithful path's routed activation, (G, P, N)
+    columns for every output pixel, would be written to HBM and read
+    back, where the decompressed kernel is a few hundred KB and one
+    convolution runs on the MXU."""
+    if jax.default_backend() == "tpu":
+        with jax.named_scope("cs_conv_mxu"):
+            y = packed_conv2d_mxu(params, x, kh, kw, stride, padding)
+        if dispatch_observed():
+            packed = params["packed"]
+            d_in = packed.shape[1] * packed.shape[2]
+            notify_dispatch({"path": "conv_mxu",
+                             "batch": int(np.prod(y.shape[:-1])),
+                             "d_in": d_in,
+                             "d_out": packed.shape[0] * packed.shape[2],
+                             "n": cfg.n, "k": cfg.k_for(d_in),
+                             "pallas": False, "interpret": False})
+        return y
     cols = im2col(x, kh, kw, stride, padding)  # (B, OH, OW, kh*kw*C)
     return packed_linear_apply(params, cols, cfg, x_is_sparse=x_is_sparse)
 

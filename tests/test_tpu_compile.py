@@ -79,3 +79,28 @@ def test_kwta_exact_compiles_at_gsc_sites(one_chip, g, d, k):
     x = jax.ShapeDtypeStruct((g, d, 1024), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda x: kwta_exact_pallas(x, k)).lower(x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("c_in,hw,n,k_frac", [(1, 32, 5, None),
+                                               (64, 14, 16, 8 / 64)])
+def test_packed_conv_compiles_at_gsc_sites(one_chip, monkeypatch, c_in, hw,
+                                           n, k_frac):
+    """GSC's conv1 and conv2 at batch 1024 in their TPU form: one
+    convolution over the decompressed kernel, with no routed gather.  The
+    backend is reported as ``"tpu"`` so that the layer takes that form."""
+    from repro.core.api import SparsityConfig
+    from repro.core.layers import packed_conv2d_apply, packed_conv2d_init
+
+    cfg = SparsityConfig(n=n, k_frac=k_frac)
+    params = jax.eval_shape(lambda k: packed_conv2d_init(
+        k, 5, 5, c_in, 64, cfg)[0], jax.random.PRNGKey(0))
+    params, x = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        (params, jax.ShapeDtypeStruct((1024, hw, hw, c_in), jnp.float32)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(lambda p, x: packed_conv2d_apply(
+        p, x, cfg, 5, 5, x_is_sparse=k_frac is not None)).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert " convolution(" in text
+    assert " gather(" not in text
